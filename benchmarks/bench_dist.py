@@ -54,12 +54,13 @@ import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=16'
 import time
 import jax, jax.numpy as jnp, numpy as np
+from repro.dist import make_mesh
 from repro.dist.bucketing import BucketPlan
 from repro.dist.collectives import gradient_sync
 from repro.launch.dryrun import collective_bytes
 
 MODES = os.environ['BENCH_DIST_MODES'].split(',')
-mesh = jax.make_mesh((2, 4, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 4, 2), ("pod", "data", "model"))
 W = 8
 rng = np.random.RandomState(0)
 g = {{f"w{{i}}": jnp.asarray(rng.randn(W, {LEAF_ELEMS}), jnp.float32)
@@ -146,7 +147,7 @@ _MODE_SETS = {
 
 
 def _measure(mode: str = "all") -> dict:
-    env = dict(os.environ, PYTHONPATH=SRC,
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
                BENCH_DIST_MODES=",".join(_MODE_SETS[mode]))
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _BODY], capture_output=True,

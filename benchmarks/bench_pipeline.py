@@ -46,6 +46,7 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import jax, numpy as np
 from dataclasses import replace
 from repro.configs import get_config
+from repro.dist import make_mesh
 from repro.models import get_model, reduced
 from repro.perf_flags import reset_flags, set_flags
 from repro.launch.dryrun import collective_bytes
@@ -61,7 +62,7 @@ loss0 = float(loss_fn(params))
 g0 = jax.grad(loss_fn)(params)
 
 for P in {STAGES}:
-    mesh = jax.make_mesh((P,), ("stage",))
+    mesh = make_mesh((P,), ("stage",))
     set_flags(pp_stages=P, microbatches=M)
     try:
         with jax.set_mesh(mesh):
@@ -87,7 +88,7 @@ for P in {STAGES}:
 
 
 def _measure() -> dict:
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _BODY], capture_output=True,
                        text=True, env=env, timeout=560)

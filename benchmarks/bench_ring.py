@@ -45,6 +45,7 @@ _BODY = f"""
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import jax, jax.numpy as jnp
+from repro.dist import make_mesh
 from repro.dist.ring import ring_attention
 from repro.launch.dryrun import collective_bytes
 
@@ -54,7 +55,7 @@ k = jnp.zeros((B, S, K, HD), jnp.float32)
 v = jnp.zeros((B, S, K, HD), jnp.float32)
 
 def measure(P, window):
-    mesh = jax.make_mesh((P,), ("model",))
+    mesh = make_mesh((P,), ("model",))
     def attn(q, k, v):
         return ring_attention(q, k, v, causal=True, window=window)
     def loss(q, k, v):
@@ -82,7 +83,7 @@ measure(8, {WINDOW})
 
 
 def _measure() -> dict:
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _BODY], capture_output=True,
                        text=True, env=env, timeout=560)

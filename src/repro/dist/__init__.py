@@ -23,8 +23,9 @@ The distribution layer of the reproduction (DESIGN.md §5, §7):
   groups with a 1F1B micro-batch schedule, collective-permute activation
   hand-offs and a reverse-schedule ``custom_vjp`` (DESIGN.md §10), plus
   its analytic bubble/permute-byte models;
-* ``compat`` — backfills ``jax.set_mesh`` / ``jax.shard_map`` on older jax
-  (imported first, for its side effects).
+* ``compat`` — ``make_mesh`` (every axis ``Auto``, so annotations are
+  partitioner hints), ``current_mesh`` and a partial-manual
+  ``shard_map``.
 
 Worked example — the full surface on a dev box (1 device, so every
 annotation is the identity and collectives degrade to local sums)::
@@ -33,7 +34,7 @@ annotation is the identity and collectives degrade to local sums)::
     >>> x = ann(jnp.ones((8, 16)), BATCH, "model")   # no mesh: identity
     >>> x.shape
     (8, 16)
-    >>> mesh = jax.make_mesh((1,), ("data",))
+    >>> mesh = make_mesh((1,), ("data",))
     >>> grads = {"w": jnp.ones((4, 6)), "b": jnp.ones((4, 2))}
     >>> out = gradient_sync(mesh, grads, mode="bucketed")
     >>> {k: v.shape for k, v in sorted(out.items())}
@@ -43,8 +44,8 @@ annotation is the identity and collectives degrade to local sums)::
     >>> plan.n_buckets, plan.assignment()
     (1, (0, 0))
 """
-from . import compat  # noqa: F401  (installs jax API backfills)
 from .annotate import BATCH, DATA_AXES, ann, ann_first_fit, _mesh_axes
+from .compat import make_mesh
 from .bucketing import (DEFAULT_BUCKET_BYTES, Bucket, BucketPlan,
                         leaf_nbytes, overlap_taps)
 from .collectives import (EventualSync, eventual_crosspod_bytes,
@@ -59,7 +60,7 @@ from .ring import RingSpec, contributing_steps, ring_attention, \
     ring_permute_bytes
 
 __all__ = [
-    "BATCH", "DATA_AXES", "ann", "ann_first_fit", "_mesh_axes",
+    "BATCH", "DATA_AXES", "ann", "ann_first_fit", "_mesh_axes", "make_mesh",
     "gradient_sync", "worker_axes", "EventualSync",
     "eventual_sync_buckets", "eventual_crosspod_bytes",
     "eventual_state_bytes",

@@ -41,7 +41,8 @@ the per-phase eventual bytes match the analytic staleness model exactly).
 Worked example (1-device fallback — runs anywhere)::
 
     >>> import jax, jax.numpy as jnp
-    >>> mesh = jax.make_mesh((1,), ("data",))
+    >>> from repro.dist import make_mesh
+    >>> mesh = make_mesh((1,), ("data",))
     >>> grads = {"w": jnp.ones((4, 3))}      # 4 workers, one 3-vector each
     >>> out = gradient_sync(mesh, grads, mode="bucketed")
     >>> out["w"].tolist()                    # leading-dim sum, same tree
@@ -51,6 +52,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
@@ -361,7 +363,9 @@ class EventualSync:
     def init_state(self) -> dict:
         """Zero remote shards, laid out ``(W, shard)`` with the worker dim
         sharded over the worker axes (``make_array_from_callback`` so the
-        same code works single- and multi-process)."""
+        same code works single- and multi-process).  The callback returns
+        host arrays: a ``jnp`` array made under the ambient mesh would
+        span every process's devices, which ``device_put`` refuses."""
         if self.degenerate:
             return {}
         from jax.sharding import NamedSharding
@@ -374,7 +378,7 @@ class EventualSync:
             def zeros_shard(idx, shape=shape, dt=dt):
                 local = tuple(len(range(*s.indices(n)))
                               for s, n in zip(idx, shape))
-                return jnp.zeros(local, dt)
+                return np.zeros(local, dt)
 
             out[f"b{k}"] = jax.make_array_from_callback(shape, sharding,
                                                         zeros_shard)

@@ -1,102 +1,50 @@
-"""jax version compatibility for the distribution layer.
+"""The mesh surface of the distribution layer, on the installed JAX.
 
-The repo is written against the modern sharding surface — ``jax.set_mesh``
-installing an ambient mesh, ``jax.shard_map`` resolving it implicitly, and
-sharding constraints expressed as bare ``PartitionSpec``s.  Older jax
-(0.4.x, the toolchain baked into this container) predates those entry
-points, so importing this module backfills them:
+Three helpers, so the rest of the package has one place to ask:
 
-* ``jax.set_mesh(mesh)`` returns the mesh itself; ``Mesh`` is a context
-  manager that installs the legacy resource env, which is exactly the
-  ambient-mesh behaviour the callers rely on;
-* ``jax.shard_map(f, in_specs=..., out_specs=..., axis_names=...,
-  check_vma=...)`` wraps ``jax.experimental.shard_map.shard_map``,
-  resolving the ambient mesh at trace time and mapping ``axis_names`` onto
-  the legacy ``auto`` set (axes *not* named stay under the partitioner).
+* ``make_mesh(shape, axes)`` builds a mesh whose axes are all
+  ``AxisType.Auto``.  ``jax.make_mesh`` defaults to ``Explicit`` axes,
+  under which every ``with_sharding_constraint`` (``annotate.ann``) is an
+  assert instead of a hint to the partitioner; this package's model code
+  states layouts as hints, so its meshes are ``Auto``;
+* ``current_mesh()`` returns the ambient concrete mesh (``jax.set_mesh``)
+  or ``None``;
+* ``shard_map(f, mesh, in_specs, out_specs, axis_names)`` makes the named
+  axes manual and leaves the others to the partitioner.
 
-``current_mesh()`` is the single place the rest of the package asks "what
-mesh am I under?" — it returns the ambient concrete mesh or ``None``, on
-every jax version we target.
+>>> make_mesh((1,), ("data",)).axis_types
+(Auto,)
 """
 from __future__ import annotations
 
 import jax
+from jax._src import mesh as mesh_lib
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (see module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def current_mesh():
-    """The ambient concrete mesh (``jax.set_mesh`` / ``with mesh:``), or
-    ``None`` when no mesh is installed."""
-    try:
-        from jax._src import mesh as mesh_lib
-    except Exception:  # pragma: no cover - future jax reshuffles internals
-        mesh_lib = None
-    if mesh_lib is not None:
-        get_concrete = getattr(mesh_lib, "get_concrete_mesh", None)
-        if get_concrete is not None:
-            try:
-                m = get_concrete()
-                # older jax returns () for "no mesh set"
-                if isinstance(m, jax.sharding.Mesh) and not m.empty:
-                    return m
-            except Exception:
-                pass
-        tr = getattr(mesh_lib, "thread_resources", None)
-        if tr is not None:
-            m = tr.env.physical_mesh
-            if isinstance(m, jax.sharding.Mesh) and not m.empty:
-                return m
-    return None
+    """The ambient concrete mesh (``jax.set_mesh``), also while tracing
+    under it, or ``None`` when no mesh is installed."""
+    m = mesh_lib.get_concrete_mesh()
+    return None if m.empty else m
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """Version-portable shard_map over ``mesh``.
+    """shard_map over ``mesh``.
 
     ``axis_names``: the axes the body addresses collectively (manual);
-    every other mesh axis is left to the partitioner (legacy ``auto``).
-    Replication checking is disabled — the dispatch bodies here mix manual
-    batch axes with auto model axes, which the checker cannot track.
+    every other mesh axis is left to the partitioner.  ``None`` makes all
+    axes manual.  Replication checking is off — the dispatch bodies here
+    mix manual batch axes with auto model axes, which the checker cannot
+    track.
     """
-    manual = frozenset(axis_names) if axis_names else frozenset(mesh.axis_names)
-    auto = frozenset(mesh.axis_names) - manual
-    modern = getattr(jax, "shard_map", None)
-    if modern is not None and modern is not _shard_map_backfill:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        try:
-            return modern(f, check_vma=False, **kw)
-        except TypeError:  # pre-check_vma spelling
-            return modern(f, check_rep=False, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, auto=auto)
-
-
-def _shard_map_backfill(f, mesh=None, in_specs=None, out_specs=None,
-                        axis_names=None, check_vma=True, **_kw):
-    """Ambient-mesh ``jax.shard_map`` for jax versions without it."""
-    def wrapped(*args):
-        m = mesh or current_mesh()
-        if m is None:
-            raise ValueError(
-                "jax.shard_map: no mesh passed and no ambient mesh installed "
-                "(enter `with jax.set_mesh(mesh):` first)")
-        return shard_map(f, m, in_specs, out_specs,
-                         axis_names=axis_names)(*args)
-    return wrapped
-
-
-def _install_backfills():
-    if not hasattr(jax, "make_mesh"):  # pragma: no cover - jax >= 0.4.35
-        def _make_mesh(shape, axis_names):
-            from jax.experimental import mesh_utils
-            devs = mesh_utils.create_device_mesh(tuple(shape))
-            return jax.sharding.Mesh(devs, tuple(axis_names))
-        jax.make_mesh = _make_mesh
-    if not hasattr(jax, "set_mesh"):
-        # Mesh is its own context manager; returning it makes
-        # `with jax.set_mesh(mesh):` install the ambient resource env.
-        jax.set_mesh = lambda mesh: mesh
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = _shard_map_backfill
-
-
-_install_backfills()
+    manual = frozenset(axis_names or mesh.axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)

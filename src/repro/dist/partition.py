@@ -29,7 +29,8 @@ mesh (no "data" axis, so FSDP entries resolve to ``None``; the scan axis
 gets the prepended ``None``)::
 
     >>> import jax
-    >>> mesh = jax.make_mesh((1,), ("model",))
+    >>> from repro.dist import make_mesh
+    >>> mesh = make_mesh((1,), ("model",))
     >>> params = {"blocks": {"wq": jax.ShapeDtypeStruct((4, 8, 2, 16),
     ...                                                 "float32")}}
     >>> specs = param_pspecs(cfg=None, params=params, mesh=mesh)

@@ -4,6 +4,10 @@ implemented well-optimized big operation", adapted to the MXU/VMEM).
 Design (TPU-native, not a CUDA port):
   * grid = (B, H, nQ, nK); the nK axis is "arbitrary" (sequential) so the
     online-softmax state (m, l, acc) lives in VMEM scratch across k-blocks;
+  * the kernel works in a heads-major ``(B, H, S, hd)`` layout (the
+    wrapper transposes the ``(B, S, H, hd)`` arguments), so a block's last
+    two dims are ``(block, head_dim)``: the TPU tiles those two dims, and
+    a single head in the second-minor dim would not be a legal tile;
   * q/k/v blocks are staged HBM->VMEM by BlockSpecs; block shapes default
     to (128, head_dim) — MXU-aligned (multiples of 128 on the matmul dims);
   * GQA: the k/v BlockSpec index_map folds the query head onto its kv head
@@ -38,10 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 
@@ -70,17 +70,17 @@ def _flash_kernel(*refs, scale, causal, window, softcap, q_offset, kv_offset,
     def _init():
         if has_carry:
             m_in, l_in, acc_in = carry_refs
-            m_scr[...] = m_in[0, :, 0, :]
-            l_scr[...] = l_in[0, :, 0, :]
-            acc_scr[...] = acc_in[0, :, 0, :]
+            m_scr[...] = m_in[0, 0]
+            l_scr[...] = l_in[0, 0]
+            acc_scr[...] = acc_in[0, 0]
         else:
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                # (bq, hd)
+    k = k_ref[0, 0].astype(jnp.float32)                # (bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -124,14 +124,14 @@ def _flash_kernel(*refs, scale, causal, window, softcap, q_offset, kv_offset,
     def _done():
         if return_carry:
             m_out, l_out, acc_out = out_refs
-            m_out[0, :, 0, :] = m_scr[...]
-            l_out[0, :, 0, :] = l_scr[...]
-            acc_out[0, :, 0, :] = acc_scr[...]
+            m_out[0, 0] = m_scr[...]
+            l_out[0, 0] = l_scr[...]
+            acc_out[0, 0] = acc_scr[...]
         else:
             (o_ref,) = out_refs
             l = l_scr[...]
             l = jnp.where(l == 0.0, 1.0, l)             # fully-masked rows
-            o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+            o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_carry_init(B, Sq, H, hd):
@@ -206,30 +206,27 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         kv_offset=kv_offset, kv_len=kv_len, block_q=block_q, block_k=block_k,
         n_k=n_k, has_carry=has_carry, return_carry=return_carry)
 
-    q_spec = pl.BlockSpec((1, block_q, 1, hd),
-                          lambda b, h, qi, ki: (b, qi, h, 0))
-    scalar_spec = pl.BlockSpec((1, block_q, 1, 1),
-                               lambda b, h, qi, ki: (b, qi, h, 0))
-    in_specs = [
-        q_spec,
-        pl.BlockSpec((1, block_k, 1, hd),
-                     lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_k, 1, hd),
-                     lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
-    ]
-    inputs = [q, k, v]
+    heads_major = lambda x: jnp.swapaxes(x, 1, 2)      # (B,S,H,.)<->(B,H,S,.)
+    q_spec = pl.BlockSpec((1, 1, block_q, hd),
+                          lambda b, h, qi, ki: (b, h, qi, 0))
+    scalar_spec = pl.BlockSpec((1, 1, block_q, 1),
+                               lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
+                           lambda b, h, qi, ki, G=G: (b, h // G, ki, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    inputs = [heads_major(q), heads_major(k), heads_major(v)]
     if has_carry:
         in_specs += [scalar_spec, scalar_spec, q_spec]
-        inputs += [m0, l0, acc0]
+        inputs += [heads_major(m0), heads_major(l0), heads_major(acc0)]
 
     if return_carry:
         out_specs = [scalar_spec, scalar_spec, q_spec]
-        out_shape = [jax.ShapeDtypeStruct((B, Sq_p, H, 1), jnp.float32),
-                     jax.ShapeDtypeStruct((B, Sq_p, H, 1), jnp.float32),
-                     jax.ShapeDtypeStruct((B, Sq_p, H, hd), jnp.float32)]
+        out_shape = [jax.ShapeDtypeStruct((B, H, Sq_p, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((B, H, Sq_p, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((B, H, Sq_p, hd), jnp.float32)]
     else:
         out_specs = [q_spec]
-        out_shape = [jax.ShapeDtypeStruct((B, Sq_p, H, hd), q.dtype)]
+        out_shape = [jax.ShapeDtypeStruct((B, H, Sq_p, hd), q.dtype)]
 
     out = pl.pallas_call(
         kernel,
@@ -242,12 +239,13 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(*inputs)
 
+    out = [heads_major(x) for x in out]
     if return_carry:
         m, l, acc = out
         if pq:

@@ -50,11 +50,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
+
+
+def legal_head_tile(head_tile, K: int, quant: bool) -> int:
+    """KV heads per grid step.  The TPU tiles a block's last two dims
+    (heads, head_dim) in (8, 128) units unless a dim is whole, so a tile
+    is a multiple of 8 that divides K, or all K heads.  Quantized pools
+    put the heads in the lane dim of their scale blocks, which then need
+    all K.  Any other request falls back to K.
+
+    >>> legal_head_tile(8, 16, False), legal_head_tile(8, 16, True)
+    (8, 16)
+    >>> legal_head_tile(16, 2, False), legal_head_tile(1, 16, False)
+    (2, 16)
+    """
+    ht = int(head_tile)
+    if quant or ht <= 0 or K % ht or (ht % 8 and ht != K):
+        return K
+    return ht
 
 
 def _paged_kernel(tables_ref, lens_ref, q_ref, *refs, scale, block_size,
@@ -133,7 +147,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, *refs, scale, block_size,
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     k_scale=None, v_scale=None, window=None, softcap=None,
-                    pages_per_step=1, head_tile=1, interpret=None):
+                    pages_per_step=1, head_tile=16, interpret=None):
     """Single-token attention through a paged KV pool.
 
     q: (B, H, hd) — the current token's query rows;
@@ -146,7 +160,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     the pools are quantized (both or neither);
     pages_per_step / head_tile: grid tunables (see module docstring) —
     pure schedule knobs, the output is bitwise independent of them up to
-    f32 summation order.
+    f32 summation order; ``head_tile`` goes through
+    :func:`legal_head_tile`.
 
     Returns (B, H, hd).  Lanes with length 0 return zeros.
     """
@@ -160,7 +175,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    ht = int(head_tile) if head_tile and K % int(head_tile) == 0 else 1
+    ht = legal_head_tile(head_tile, K, quant)
     pps = max(1, min(int(pages_per_step), n_pages))
     pad = (-n_pages) % pps
     tables = block_tables.astype(jnp.int32)
@@ -213,7 +228,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(tables, lengths.astype(jnp.int32), *inputs)

@@ -179,8 +179,8 @@ def _register_all():
     register(OpSpec(
         name="paged_attention", impl=paged_attention,
         reference=ref.paged_attention_ref,
-        tunables={"pages_per_step": (1, 2, 4), "head_tile": (1, 2)},
-        defaults={"pages_per_step": 1, "head_tile": 1},
+        tunables={"pages_per_step": (1, 2, 4), "head_tile": (8, 16)},
+        defaults={"pages_per_step": 1, "head_tile": 16},
         bucket_of=paged_bucket,
         bench_cases=(
             ("decode_B4", paged_case(4, 8, 40, 16, 8, 2, 64)),
@@ -241,7 +241,7 @@ def _register_all():
         name="sample_tokens", impl=sample_tokens,
         reference=ref.sample_ref,
         tunables={"rows_per_step": (1, 4, 8)},
-        defaults={"rows_per_step": 4},
+        defaults={"rows_per_step": 8},
         bucket_of=sample_bucket,
         bench_cases=(("B8_V512", sample_case(8, 512)),
                      ("B16_V2048", sample_case(16, 2048)))))

@@ -8,7 +8,11 @@ if "--xla_force_host_platform_device_count" not in os.environ.get(
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=512").strip()
 
-"""Multi-pod dry-run (deliverable e).
+"""Multi-pod dry-run (deliverable e) — a CPU tool.
+
+It builds its 256/512-device meshes from virtual CPU devices (the
+``XLA_FLAGS`` above) and pins itself to the CPU platform, so on a machine
+with a chip it never takes the chip nor resolves to one device.
 
 For every (architecture × input shape) pair, lower + compile the step
 function on the production meshes (16×16 single-pod, 2×16×16 multi-pod),
@@ -339,6 +343,7 @@ def main():
     ap.add_argument("--force", action="store_true",
                     help="recompute even if a result JSON exists")
     args = ap.parse_args()
+    jax.config.update("jax_platforms", "cpu")   # virtual devices only
 
     pairs = ([(a, s) for a in ARCH_IDS for s in INPUT_SHAPES]
              if args.all else [(args.arch, args.shape)])

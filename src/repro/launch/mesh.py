@@ -19,6 +19,8 @@ import os
 import jax
 import numpy as np
 
+from repro.dist.compat import make_mesh
+
 
 def initialize_distributed(coordinator_address: str | None = None,
                            num_processes: int | None = None,
@@ -49,10 +51,8 @@ def initialize_distributed(coordinator_address: str | None = None,
     from jax._src import distributed as _dist
     if getattr(_dist.global_state, "client", None) is not None:
         return True     # already in a group
-    try:   # CPU collectives backend: only gloo supports cross-process
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - config renamed on newer jax
-        pass
+    # CPU collectives backend: only gloo supports cross-process
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -108,12 +108,12 @@ def make_production_mesh(*, multi_pod: bool = False, pp_stages: int = 1,
     if multi_pod:
         shape = (2,) + shape
         axes = ("pod",) + axes
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs of the pjit code path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline targets; DESIGN.md §6)

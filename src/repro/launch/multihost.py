@@ -255,6 +255,7 @@ def _spawn_group(args, n_procs: int, extra: list[str]) -> None:
         env.update(
             REPRO_COORDINATOR=f"127.0.0.1:{port}",
             REPRO_NUM_PROCS=str(n_procs), REPRO_PROC_ID=str(i),
+            JAX_PLATFORMS="cpu",        # virtual CPU devices, never a chip
             XLA_FLAGS="--xla_force_host_platform_device_count="
                       f"{args.local_devices}")
         cmd = [sys.executable, "-m", "repro.launch.multihost", "--worker",

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import get_model, reduced
 from repro.serve import PagedServeEngine, ServeEngine
 
@@ -98,6 +99,7 @@ def main():
                     help="write the final metrics snapshot (TTFT/TPOT/"
                          "queue-wait histograms, pool gauges) as JSONL")
     args = ap.parse_args()
+    use_compile_cache()
 
     from repro import obs
     if args.trace:

@@ -14,6 +14,7 @@ import argparse
 import jax
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.data import PrefetchIterator, SyntheticLM
 from repro.models import reduced
 from repro.train import TrainConfig, Trainer
@@ -84,6 +85,7 @@ def main():
                     help="write per-step records and the final metrics "
                          "snapshot as JSONL")
     args = ap.parse_args()
+    use_compile_cache()
 
     from repro import obs
     if args.trace:

@@ -5,9 +5,11 @@ Decode attention is written so GSPMD can shard the KV-cache *sequence* dim:
 scores/softmax/value-combine keep S as a contraction dim, letting XLA lower
 the distributed-softmax (flash-decoding) pattern with small collectives.
 
-When ``use_pallas`` is enabled (TPU), attention and RMSNorm route to the
-Pallas kernels in ``repro.kernels`` (the paper's "manually implemented
-well-optimized big operations").
+Paged decode attention runs the Pallas kernel in ``repro.kernels`` (the
+paper's "manually implemented well-optimized big operations") whenever the
+backend is a TPU.  Full-sequence attention and RMSNorm route to their
+kernels only when ``set_use_pallas(True)``: the flash kernel has no
+backward pass yet, so training stays on the jnp path.
 """
 from __future__ import annotations
 
@@ -17,7 +19,7 @@ import numpy as np
 
 from repro.dist.annotate import BATCH, ann
 
-# toggled by configs/launchers; False on CPU (Pallas only interprets there)
+# flash attention + RMSNorm kernels on the full-sequence path (tests only)
 _USE_PALLAS = False
 
 
@@ -255,14 +257,15 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """One-token attention through the paged pool (DESIGN.md §9).
 
     q: (B, H, hd); pools: (NB, bs, K, hd); block_tables: (B, P);
-    lengths: (B,) live tokens including the current one.  Routes to the
-    Pallas paged kernel on TPU; on CPU the gather-based oracle is the
-    fast path (interpret-mode Pallas runs the grid in Python).
+    lengths: (B,) live tokens including the current one.  Runs the
+    compiled Pallas paged kernel when the backend is a TPU; elsewhere the
+    gather-based oracle is the fast path (interpret-mode Pallas runs the
+    grid in Python).
     ``k_scale``/``v_scale``: (NB, bs, K) f32 per-row scales when the
     pools are quantized (DESIGN.md §13); both paths fuse the dequant into
     attention — no full-precision cache copy.
     """
-    if _USE_PALLAS:
+    if jax.default_backend() == "tpu":
         from repro.kernels.ops import paged_attention
         return paged_attention(q, k_pages, v_pages, block_tables, lengths,
                                k_scale=k_scale, v_scale=v_scale,

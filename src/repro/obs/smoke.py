@@ -28,8 +28,10 @@ import json
 import os
 import sys
 
-# the pipeline schedule needs a real multi-device "stage" axis; must be
-# set before jax initializes (same trick as benchmarks/bench_dist.py)
+# a CPU tool: the pipeline schedule needs a real multi-device "stage"
+# axis, made of virtual CPU devices that must be requested before jax
+# initializes (same trick as benchmarks/bench_dist.py); main() pins the
+# CPU platform so a machine with a chip never hands it one chip instead
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=2")
 
@@ -67,6 +69,7 @@ def _serve_layer(cfg, params):
 def _dist_layer():
     import jax
     import jax.numpy as jnp
+    from repro.dist import make_mesh
     from repro.dist.collectives import gradient_sync
     from repro.dist.pipeline import pipeline_stack
     from repro.dist.ring import ring_attention
@@ -82,7 +85,7 @@ def _dist_layer():
         return
 
     # pipeline: 2 stages x 2 microbatches over a forced host-device mesh
-    mesh = jax.make_mesh((2,), ("stage",))
+    mesh = make_mesh((2,), ("stage",))
     params = {"w": jnp.eye(4)[None].repeat(2, 0)}
 
     def stage_fn(p, x):
@@ -96,7 +99,7 @@ def _dist_layer():
         pipeline_stack(stage_fn, params, x, microbatches=2, mesh=mesh)
 
     # bucketed gradient sync: per-bucket collective chains
-    dmesh = jax.make_mesh((2,), ("data",))
+    dmesh = make_mesh((2,), ("data",))
     gradient_sync(dmesh, {"w": jnp.ones((2, 5))}, mode="bucketed")
 
 
@@ -141,6 +144,8 @@ def main() -> None:
     ap.add_argument("--trace", metavar="PATH", required=True)
     ap.add_argument("--metrics", metavar="PATH", default=None)
     args = ap.parse_args()
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
     from repro import obs
     from repro.configs import get_config

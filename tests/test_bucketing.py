@@ -12,7 +12,7 @@ import pytest
 
 from hypothesis_compat import given, settings, st
 from mesh_subproc import run_sub
-from repro.dist import gradient_sync
+from repro.dist import gradient_sync, make_mesh
 from repro.dist.bucketing import BucketPlan, leaf_nbytes, overlap_taps
 
 
@@ -125,7 +125,8 @@ def test_bucketed_sync_matches_flat_on_mesh():
     out = run_sub("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.dist import gradient_sync
-    mesh = jax.make_mesh((2, 4, 2), ("pod", "data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((2, 4, 2), ("pod", "data", "model"))
     W = 8
     rng = np.random.RandomState(0)
     grads = {"a": jnp.asarray(rng.randn(W, 3, 5), jnp.float32),
@@ -145,7 +146,7 @@ def test_bucketed_sync_matches_flat_on_mesh():
 
 
 def test_bucketed_sync_no_mesh_fallback():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     grads = {"w": jnp.arange(12, dtype=jnp.float32).reshape(4, 3)}
     out = gradient_sync(mesh, grads, mode="bucketed")
     np.testing.assert_array_equal(np.asarray(out["w"]),
@@ -211,7 +212,8 @@ def test_trainer_overlap_step_on_mesh():
                   d_model=64, d_ff=128)
     data = {"tokens": jnp.asarray(
         np.random.RandomState(0).randint(0, 32, (8, 16)))}
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     losses = []
     with jax.set_mesh(mesh):
         for overlap in (False, True):

@@ -121,8 +121,9 @@ def test_elastic_roundtrip_all_archs():
     from repro.dist.partition import make_shardings, param_pspecs
     from repro.train import load_checkpoint, save_checkpoint
 
-    mesh_a = jax.make_mesh((2, 2), ("data", "model"))
-    mesh_b = jax.make_mesh((4,), ("model",))
+    from repro.dist import make_mesh
+    mesh_a = make_mesh((2, 2), ("data", "model"))
+    mesh_b = make_mesh((4,), ("model",))
     for arch in ARCH_IDS:
         cfg = reduced(get_config(arch))
         params = get_model(cfg).init(jax.random.PRNGKey(0))
@@ -166,14 +167,15 @@ def test_elastic_roundtrip_opt_state_and_stage_mesh():
     state = {"params": params, "opt": sgd_momentum().init(params)}
     ref = [np.asarray(x) for x in jax.tree.leaves(jax.device_get(state))]
 
-    mesh_pp = jax.make_mesh((2, 2), ("stage", "data"))
+    from repro.dist import make_mesh
+    mesh_pp = make_mesh((2, 2), ("stage", "data"))
     sharded = jax.device_put(
         state, make_shardings(mesh_pp, stage_pspecs(None, state, mesh_pp)))
     d = tempfile.mkdtemp()
     save_checkpoint(d, sharded, step=3)
 
     # pipelined 2x2 -> unpipelined 1x4
-    mesh_b = jax.make_mesh((4,), ("model",))
+    mesh_b = make_mesh((4,), ("model",))
     rb, step = load_checkpoint(d, like=state, mesh=mesh_b)
     assert step == 3
     for a, b in zip(jax.tree.leaves(jax.device_get(rb)), ref):
@@ -185,7 +187,7 @@ def test_elastic_roundtrip_opt_state_and_stage_mesh():
         assert np.array_equal(np.asarray(a), b)
 
     # dp-style save -> restore INTO an ambient stage mesh (grow the run)
-    mesh_dp = jax.make_mesh((4,), ("data",))
+    mesh_dp = make_mesh((4,), ("data",))
     d2 = tempfile.mkdtemp()
     save_checkpoint(d2, jax.device_put(
         state, make_shardings(mesh_dp, param_pspecs(None, state, mesh_dp))))
@@ -255,9 +257,10 @@ def test_resume_parity_across_mesh_reshapes():
         return losses(tr2)
 
     import contextlib
-    mesh22 = lambda: jax.set_mesh(jax.make_mesh((2, 2), ("data", "model")))
-    mesh14 = lambda: jax.set_mesh(jax.make_mesh((4,), ("model",)))
-    mesh41 = lambda: jax.set_mesh(jax.make_mesh((4,), ("data",)))
+    from repro.dist import make_mesh
+    mesh22 = lambda: jax.set_mesh(make_mesh((2, 2), ("data", "model")))
+    mesh14 = lambda: jax.set_mesh(make_mesh((4,), ("model",)))
+    mesh41 = lambda: jax.set_mesh(make_mesh((4,), ("data",)))
     single = contextlib.nullcontext
 
     for name, (ma, mb) in {"2x2->1x4": (mesh22, mesh14),

@@ -14,7 +14,8 @@ def test_hierarchical_allreduce_matches_flat():
     out = run_sub("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.dist import gradient_sync
-    mesh = jax.make_mesh((2, 4, 2), ("pod", "data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((2, 4, 2), ("pod", "data", "model"))
     W = 8  # pod*data workers
     rng = np.random.RandomState(0)
     grads = {"a": jnp.asarray(rng.randn(W, 3, 5), jnp.float32),
@@ -38,7 +39,8 @@ def test_hierarchical_reduces_interpod_bytes():
     out = run_sub("""
     import jax, jax.numpy as jnp, re
     from repro.dist.collectives import gradient_sync
-    mesh = jax.make_mesh((2, 4, 2), ("pod", "data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((2, 4, 2), ("pod", "data", "model"))
     W = 8
     g = {"w": jnp.zeros((W, 4096), jnp.float32)}
     with jax.set_mesh(mesh):
@@ -70,7 +72,8 @@ def test_param_pspecs_cover_tree_and_divide():
     from repro.configs import get_config
     from repro.dist import param_pspecs
     from repro.models import get_model
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     for arch in ("dbrx-132b", "mamba2-130m", "gemma2-2b", "whisper-base"):
         cfg = get_config(arch)
         specs = param_pspecs(cfg, get_model(cfg).param_specs(), mesh)
@@ -101,7 +104,8 @@ def test_dryrun_single_pair_tiny():
     from dataclasses import replace
     from repro.models import reduced
     cfg = reduced(get_config("qwen1.5-0.5b"))
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     import repro.models.common as C
     import repro.launch.steps as S
     # shrink the input shape table for the test
@@ -129,7 +133,8 @@ def test_decode_step_lowering_tiny():
     import repro.models.common as C
     import repro.launch.steps as S
     cfg = reduced(get_config("gemma2-2b"))
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4, 4), ("data", "model"))
     S.INPUT_SHAPES = dict(S.INPUT_SHAPES)
     S.INPUT_SHAPES["decode_32k"] = C.InputShape("decode_32k", 256, 8, "decode")
     lowered, compiled, tl, tc = lower_and_compile(cfg, "decode_32k", mesh)

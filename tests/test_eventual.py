@@ -11,6 +11,7 @@ sys.path.insert(0, str(Path(__file__).parent))
 from hypothesis_compat import given, settings, st  # noqa: E402
 from mesh_subproc import run_sub  # noqa: E402
 
+from repro.dist import make_mesh
 from repro.dist.bucketing import BucketPlan
 from repro.dist.collectives import (EventualSync, eventual_crosspod_bytes,
                                     eventual_state_bytes,
@@ -118,14 +119,14 @@ def test_memplan_model_matches_collectives_model():
 
 
 def test_eventual_sync_validates_args():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="max_staleness"):
         EventualSync(mesh, {"w": jax.ShapeDtypeStruct((1, 8), "float32")},
                      max_staleness=-1)
 
 
 def test_degenerate_on_single_worker_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ev = EventualSync(mesh, {"w": jax.ShapeDtypeStruct((1, 8), "float32")},
                       max_staleness=3)
     assert ev.degenerate
@@ -150,7 +151,8 @@ def test_staleness0_bit_exact_and_hlo_bytes_on_mesh():
     from repro.dist.collectives import EventualSync, gradient_sync
     from repro.launch.dryrun import collective_bytes
 
-    mesh = jax.make_mesh((2, 4, 2), ("pod", "data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((2, 4, 2), ("pod", "data", "model"))
     W = 8
     rng = np.random.default_rng(0)
     g = {f"w{i}": jnp.asarray(rng.normal(size=(W, 700 + 100 * i)),
@@ -191,7 +193,8 @@ def test_trainer_eventual_staleness0_matches_sequential():
 
     cfg = reduced(get_config("qwen1.5-0.5b"), vocab=32, n_layers=2,
                   d_model=64, d_ff=128)
-    mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
 
     def run(mode, ms=0):
         data = SyntheticLM(32, 16, 8, seed=1, n_batches=3)

@@ -288,11 +288,11 @@ def test_paged_attention_zero_length_lane_is_zero():
 # head_tile never change results, only the grid
 
 @pytest.mark.parametrize("pps", [1, 2, 4, 5])
-@pytest.mark.parametrize("ht", [1, 2])
+@pytest.mark.parametrize("ht", [8, 16])
 def test_paged_attention_schedule_tunables(pps, ht):
     from repro.kernels.paged_attention import paged_attention
     q, kp, vp, tables, lengths = _paged_case(
-        B=3, H=4, K=2, hd=32, bs=8, NB=17, P=5, lengths=[19, 33, 40])
+        B=3, H=16, K=16, hd=32, bs=8, NB=17, P=5, lengths=[19, 33, 40])
     out = paged_attention(q, kp, vp, tables, lengths,
                           pages_per_step=pps, head_tile=ht)
     want = ref.paged_attention_ref(q, kp, vp, tables, lengths)

@@ -43,7 +43,8 @@ def test_hlo_matches_analytic_ratio():
     import jax, jax.numpy as jnp
     from repro.dist.collectives import gradient_sync
     from repro.launch.dryrun import collective_bytes
-    mesh = jax.make_mesh(({N_MACHINES}, {DEVS_PER_MACHINE}, 2),
+    from repro.dist import make_mesh
+    mesh = make_mesh(({N_MACHINES}, {DEVS_PER_MACHINE}, 2),
                          ("pod", "data", "model"))
     W = {N_MACHINES * DEVS_PER_MACHINE}
     g = {{"w": jnp.zeros((W, {N_PARAM}), jnp.float32)}}

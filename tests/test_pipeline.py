@@ -103,9 +103,9 @@ def test_stage_pspecs_and_worker_axes():
     worker axes never include "stage" (buckets reduce over data/pod only
     — DESIGN.md §10)."""
     from jax.sharding import PartitionSpec as P
-    from repro.dist import worker_axes
+    from repro.dist import make_mesh, worker_axes
     from repro.dist.pipeline import stage_pspecs
-    mesh = jax.make_mesh((1, 1), ("stage", "data"))
+    mesh = make_mesh((1, 1), ("stage", "data"))
     params = {"embed": jax.ShapeDtypeStruct((512, 64), "float32"),
               "blocks": {"p0": {"wq": jax.ShapeDtypeStruct((4, 64, 8, 16),
                                                            "float32")}}}
@@ -186,7 +186,8 @@ def test_pipeline_stack_4_stages_fwd_bwd():
         return (y * dyw).sum() + 0.5 * aux["lb"]
     g0w, g0x = jax.grad(loss, argnums=(0, 1))(w, x)
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4,), ("stage",))
     with jax.set_mesh(mesh):
         y1, aux1 = jax.jit(
             lambda w, x: pipeline_stack(stage_fn, w, x, microbatches=M)
@@ -233,7 +234,8 @@ def test_pipeline_moe_arch_runs_on_stage_mesh():
     loss_fn = lambda p: m.loss(p, batch)[0]
     loss0 = float(loss_fn(params))
 
-    mesh = jax.make_mesh((2, 2), ("stage", "data"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((2, 2), ("stage", "data"))
     set_flags(pp_stages=2, microbatches=2)
     try:
         with jax.set_mesh(mesh):
@@ -272,13 +274,14 @@ def test_pipeline_model_pp_x_dp_matches_data_parallel():
     g0 = jax.tree.leaves(jax.grad(loss_fn)(params))
 
     # pure data parallelism (1 x 4)
-    mesh_dp = jax.make_mesh((4,), ("data",))
+    from repro.dist import make_mesh
+    mesh_dp = make_mesh((4,), ("data",))
     with jax.set_mesh(mesh_dp):
         loss_dp = float(jax.jit(loss_fn)(params))
         g_dp = jax.tree.leaves(jax.jit(jax.grad(loss_fn))(params))
 
     # pipeline x data (2 x 2)
-    mesh_pp = jax.make_mesh((2, 2), ("stage", "data"))
+    mesh_pp = make_mesh((2, 2), ("stage", "data"))
     set_flags(pp_stages=2, microbatches=2)
     try:
         with jax.set_mesh(mesh_pp):

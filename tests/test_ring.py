@@ -103,7 +103,8 @@ def test_ring_matches_ref_4_shards_fwd_bwd():
     k = jax.random.normal(ks[1], (B, S, K, hd))
     v = jax.random.normal(ks[2], (B, S, K, hd))
     w = jax.random.normal(ks[3], (B, S, H, hd))
-    mesh = jax.make_mesh((4,), ("model",))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4,), ("model",))
     for kw in (dict(causal=True), dict(causal=True, window=48),
                dict(causal=True, window=100, softcap=15.0)):
         want = ref.flash_attention_ref(q, k, v, **kw)
@@ -150,7 +151,8 @@ def test_ring_pallas_inner_4_shards():
     k = jax.random.normal(ks[1], (B, S, K, hd))
     v = jax.random.normal(ks[2], (B, S, K, hd))
     w = jax.random.normal(ks[3], (B, S, H, hd))
-    mesh = jax.make_mesh((4,), ("model",))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4,), ("model",))
     for kw in (dict(causal=True), dict(causal=True, window=40)):
         want = ref.flash_attention_ref(q, k, v, **kw)
         with jax.set_mesh(mesh):
@@ -187,7 +189,8 @@ def test_seq_shard_model_loss_and_grads_match():
     batch = m.make_batch(jax.random.PRNGKey(1), "train", 2, 64)
     loss0, _ = m.loss(params, batch)
     g0 = jax.grad(lambda p: m.loss(p, batch)[0])(params)
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((1, 4), ("data", "model"))
     set_flags(seq_shard=True, attn_impl="auto")
     try:
         with jax.set_mesh(mesh):
@@ -218,7 +221,8 @@ def test_ring_hlo_permute_bytes_match_analytic():
     q = jnp.zeros((B, S, H, hd), jnp.float32)
     k = jnp.zeros((B, S, K, hd), jnp.float32)
     v = jnp.zeros((B, S, K, hd), jnp.float32)
-    mesh = jax.make_mesh((4,), ("model",))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4,), ("model",))
     for window in (None, 48):
         model = ring_permute_bytes(B, S, K, hd, 4, itemsize=4,
                                    causal=True, window=window)
@@ -244,7 +248,8 @@ def test_batch_pspecs_seq_kind():
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.dist import batch_pspecs
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     batch = {"tokens": jax.ShapeDtypeStruct((8, 64), "int32"),
              "patches": jax.ShapeDtypeStruct((8, 64, 32), "float32"),
              "scalar": jax.ShapeDtypeStruct((), "int32")}

@@ -205,7 +205,8 @@ def test_trained_checkpoint_serves_identically():
     tcfg = TrainConfig(lr=1e-2, total_steps=4, warmup_steps=1, log_every=2,
                        checkpoint_every=3, checkpoint_dir=root)
     tr = Trainer(cfg, tcfg)
-    with jax.set_mesh(jax.make_mesh((2, 2), ("data", "model"))):
+    from repro.dist import make_mesh
+    with jax.set_mesh(make_mesh((2, 2), ("data", "model"))):
         params, _ = tr.fit(iter(SyntheticLM(cfg.vocab, 32, 4, n_batches=4)))
     tr.wait_for_checkpoint()
     # NOTE: checkpoint lands at step 3 (the last update), so the saved
@@ -232,12 +233,23 @@ def test_trained_checkpoint_serves_identically():
 # ---------------------------------------------------------------------------
 # quantized KV-cache serving + fused sampling (DESIGN.md §13)
 
+# Largest logit change the int8 cache may cause on the reduced qwen config
+# (per-row symmetric int8 K/V; about 0.015 is seen).  An argmax can only
+# flip where the fp logits of the two tokens lie within 2x this of each
+# other.
+INT8_LOGIT_BOUND = 0.02
+
+
 def test_paged_int8_greedy_parity_and_bytes():
     """int8 paged engine: same greedy tokens as the fp engine on a short
-    workload, at the byte-model-predicted fraction of the fp cache (the
-    per-row f32 scales included, peak block count identical)."""
+    workload, up to a near-tie — where a request's streams first differ,
+    the fp logits of the two tokens must lie within 2 x INT8_LOGIT_BOUND
+    (the streams are free to differ after that) — at the byte-model-
+    predicted fraction of the fp cache (the per-row f32 scales included,
+    peak block count identical)."""
     from repro.core.memplan import kv_cache_bytes_paged
     cfg, params = _setup("qwen1.5-0.5b")
+    model = get_model(cfg)
     prompts = _prompts(cfg, (16, 24, 32), seed=2)
     outs, stats = {}, {}
     for kd in (None, "int8"):
@@ -245,8 +257,19 @@ def test_paged_int8_greedy_parity_and_bytes():
                                max_len=48, prefill_chunk=16, kv_dtype=kd)
         outs[kd], stats[kd] = eng.generate(prompts, max_new_tokens=6,
                                            warmup=False)
-    assert [list(map(int, o)) for o in outs["int8"]] == \
-        [list(map(int, o)) for o in outs[None]]
+    matched = 0
+    for prompt, fp, q in zip(prompts, outs[None], outs["int8"]):
+        fp, q = list(map(int, fp)), list(map(int, q))
+        j = next((i for i, (a, b) in enumerate(zip(fp, q)) if a != b), None)
+        if j is None:
+            matched += 1
+            continue
+        seq = np.asarray([prompt + fp[:j]], np.int32)
+        logits, _ = model.prefill(params, {"tokens": seq})
+        row = np.asarray(logits, np.float32).reshape(-1)
+        assert row.argmax() == fp[j]
+        assert row[fp[j]] - row[q[j]] <= 2 * INT8_LOGIT_BOUND, (j, fp, q)
+    assert matched >= 2, (outs[None], outs["int8"])
     assert stats["int8"].peak_cache_blocks == stats[None].peak_cache_blocks
     # measured peak == model EXACTLY, and >= 1.8x below fp
     blocks = stats["int8"].peak_cache_blocks
