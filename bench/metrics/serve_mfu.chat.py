@@ -1,0 +1,6 @@
+"""Model FLOP/s of the chat window's prefill and decode tokens over the bf16 peak (model step)."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.serve_mfu(ctx)
