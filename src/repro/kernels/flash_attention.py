@@ -242,6 +242,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(*inputs)
 

@@ -58,6 +58,7 @@ def sgd_momentum(param, grad, mom, *, lr=1e-3, mu=0.9, weight_decay=1e-4,
         out_shape=[jax.ShapeDtypeStruct(p2.shape, param.dtype),
                    jax.ShapeDtypeStruct(m2.shape, jnp.float32)],
         input_output_aliases={0: 0, 2: 1},
+        name="sgd_momentum",
         interpret=interpret,
     )(p2, g2, m2)
     new_p = new_p.reshape(-1)[:n].reshape(shape)

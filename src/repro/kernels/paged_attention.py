@@ -230,6 +230,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(tables, lengths.astype(jnp.int32), *inputs)
     return out.reshape(B, H, hd)

@@ -45,6 +45,7 @@ def rmsnorm(x, weight, eps=1e-6, block_rows=256, interpret=None):
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xr.shape, x.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(xr, weight.reshape(1, D))
     if pad:

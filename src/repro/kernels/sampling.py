@@ -171,6 +171,7 @@ def sample_tokens(logits, u, *, temperature=1.0, top_k=None, top_p=None,
         out_shape=jax.ShapeDtypeStruct((n_tiles, rb, 1), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",), vmem_limit_bytes=vmem),
+        name="sample_tokens",
         interpret=interpret,
     )(logits.reshape(n_tiles, rb, V),
       u.astype(jnp.float32).reshape(n_tiles, rb, 1))

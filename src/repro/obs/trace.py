@@ -3,20 +3,31 @@
 The runtime lens the MXNet paper's systems story needs (and the
 TensorFlow whitepaper ships as EEG): the dependency engine's waves, the
 trainer's data-wait/step/checkpoint cadence and the serving engine's
-per-request lifecycle all record onto one timeline that Perfetto /
-``chrome://tracing`` opens directly (DESIGN.md §11).
+per-request lifecycle and step phases all record onto one timeline that
+Perfetto / ``chrome://tracing`` opens directly (DESIGN.md §11).
 
 Design constraints:
 
 * **~zero overhead when disabled** — the common case.  ``span()`` on a
   disabled recorder returns a shared ``nullcontext`` (no allocation, one
-  attribute check); ``instant``/``counter`` return immediately.  The
-  acceptance gate: bench_serving decode tok/s within 2% of no-obs.
+  attribute check); ``instant``/``counter``/``complete`` return
+  immediately, and no GC callback is installed.
+* **one clock with the device trace** — while enabled, every ``span()``
+  also enters a ``jax.profiler.TraceAnnotation`` of the same name, so a
+  ``jax.profiler`` trace holds the span on its host plane, on the device
+  ops' clock.  Events recorded after the fact (``complete``, compile and
+  GC spans) stay on this recorder's clock.
+* **compile and GC spans** — between ``enable(True)`` and
+  ``enable(False)`` every ``/jax/core/compile/*`` event becomes a
+  ``compile`` span (track ``jit``, with its ``stage`` and ``fun_name``)
+  and every garbage collection a ``gc`` span (track ``gc``, with its
+  ``generation``).
 * **thread-safe** — the engine executes ops from waiter threads and the
   data pipeline prefetches on background threads; events append under a
-  lock, and each thread's events land on its own track by default.
-* **dependency-free** — stdlib only; jax is imported lazily and only for
-  the optional device-profile alignment wrappers.
+  reentrant lock (a GC callback may fire while the lock is held), and
+  each thread's events land on its own track by default.
+* **dependency-free** — stdlib only; jax is imported lazily, and only
+  while tracing.
 
 Event model (Chrome trace-event format, the subset Perfetto renders):
 
@@ -27,17 +38,20 @@ Event model (Chrome trace-event format, the subset Perfetto renders):
 
 Tracks are logical names ("engine", "trainer", "serve", "req3"), mapped
 to stable ``tid`` ints at first use; ``pid`` is always 1 (one host
-process — device timelines come from ``jax.profiler`` alignment, not
-from this recorder).
+process — device timelines come from ``jax.profiler``, not from this
+recorder).
 
 Worked example (pure host tracing — runs anywhere)::
 
     >>> rec = TraceRecorder(enabled=True)
     >>> with rec.span("outer", cat="demo"):
-    ...     with rec.span("inner", cat="demo"):
+    ...     with rec.span("inner", cat="demo") as args:
+    ...         args["n"] = 2                   # args known at the end
     ...         rec.instant("tick", cat="demo")
     >>> [e["name"] for e in rec.events()]       # inner closes first
     ['tick', 'inner', 'outer']
+    >>> rec.events()[1]["args"]
+    {'n': 2}
     >>> doc = rec.export()
     >>> sorted(doc) == ['displayTimeUnit', 'traceEvents']
     True
@@ -45,6 +59,7 @@ Worked example (pure host tracing — runs anywhere)::
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import threading
 import time
@@ -71,7 +86,7 @@ class TraceRecorder:
     def __init__(self, enabled: bool = False):
         self.enabled = enabled
         self._events: list[dict] = []
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._t0 = time.perf_counter()
         self._tracks: dict[str, int] = {}
         self._tls = threading.local()
@@ -104,25 +119,30 @@ class TraceRecorder:
     # -- recording ---------------------------------------------------------
     @contextlib.contextmanager
     def _span(self, name, cat, track, args):
-        t0 = self.now_us()
-        try:
-            yield
-        finally:
-            t1 = self.now_us()
-            ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
-                  "dur": t1 - t0, "pid": 1}
-            if args:
-                ev["args"] = args
-            with self._lock:
-                ev["tid"] = self._tid(track)
-                self._events.append(ev)
+        with _profiler_annotation(name):
+            t0 = self.now_us()
+            try:
+                yield args
+            finally:
+                t1 = self.now_us()
+                ev = {"name": name, "cat": cat, "ph": "X", "ts": t0,
+                      "dur": t1 - t0, "pid": 1}
+                if args:
+                    ev["args"] = args
+                with self._lock:
+                    ev["tid"] = self._tid(track)
+                    self._events.append(ev)
 
     def span(self, name: str, cat: str = "host", track: str | None = None,
              **args):
-        """Context manager recording one complete event around its body.
+        """Context manager recording one complete event around its body,
+        and a ``jax.profiler.TraceAnnotation`` of the same name.  The
+        ``with`` binds the event's args dict, so the body can add args
+        it learns on the way.
 
-        Disabled recorders return a shared ``nullcontext`` — the hot-path
-        cost of an un-traced span is one attribute check.
+        Disabled recorders return a shared ``nullcontext`` (which binds
+        None) — the hot-path cost of an un-traced span is one attribute
+        check.
         """
         if not self.enabled:
             return _NULL
@@ -197,6 +217,12 @@ class TraceRecorder:
 # module-level default recorder (what the instrumented layers talk to)
 
 _RECORDER = TraceRecorder(enabled=False)
+# the recorder enable() switched on: compile and GC spans go to it
+_WATCHED: TraceRecorder | None = None
+_LISTENING = False          # the jax.monitoring listener is registered
+_GC_START = 0.0             # perf_counter() at the running collection's start
+
+COMPILE_EVENT = "/jax/core/compile/"
 
 
 def get_recorder() -> TraceRecorder:
@@ -204,20 +230,73 @@ def get_recorder() -> TraceRecorder:
 
 
 def set_recorder(rec: TraceRecorder) -> TraceRecorder:
+    """Install ``rec`` as the default recorder.  Compile and GC spans
+    stop: only ``enable()`` starts them."""
     global _RECORDER
     _RECORDER = rec
+    _watch(None)
     return _RECORDER
 
 
 def enable(enabled: bool = True) -> TraceRecorder:
     """Turn the default recorder on/off (fresh event buffer when enabling
-    from off, so a CLI's --trace starts a clean timeline)."""
+    from off, so a CLI's --trace starts a clean timeline).  On, it also
+    records a ``compile`` span per JAX compile stage and a ``gc`` span
+    per garbage collection; off, the GC callback is removed."""
     global _RECORDER
     if enabled and not _RECORDER.enabled:
         _RECORDER = TraceRecorder(enabled=True)
     else:
         _RECORDER.enabled = enabled
+    _watch(_RECORDER if enabled else None)
     return _RECORDER
+
+
+def _watch(rec: TraceRecorder | None) -> None:
+    """Send compile and GC spans to ``rec`` (None: nowhere).  The JAX
+    listener is registered once and returns at once while nothing is
+    watched; the GC callback is installed only while something is."""
+    global _WATCHED, _LISTENING
+    _WATCHED = rec
+    if rec is None:
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+        return
+    if not _LISTENING:
+        try:
+            from jax import monitoring
+        except ImportError:     # jax absent: GC spans only
+            pass
+        else:
+            monitoring.register_event_time_span_listener(_on_jax_event)
+            _LISTENING = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def _on_jax_event(event: str, start_s: float, end_s: float, **kw):
+    """``jax.monitoring`` time-span listener: a compile stage (wall-clock
+    seconds) becomes a ``compile`` span on the ``jit`` track."""
+    rec = _WATCHED
+    if rec is None or not rec.enabled or not event.startswith(COMPILE_EVENT):
+        return
+    shift = time.perf_counter() - time.time()
+    rec.complete("compile", rec.to_us(start_s + shift),
+                 rec.to_us(end_s + shift), cat="jit", track="jit",
+                 stage=event[len(COMPILE_EVENT):],
+                 fun_name=str(kw.get("fun_name", "")))
+
+
+def _on_gc(phase: str, info: dict):
+    """``gc.callbacks`` hook: one ``gc`` span per collection."""
+    global _GC_START
+    if phase == "start":
+        _GC_START = time.perf_counter()
+        return
+    rec = _WATCHED
+    if rec is not None and rec.enabled:
+        rec.complete("gc", rec.to_us(_GC_START), rec.now_us(), cat="gc",
+                     track="gc", generation=info.get("generation"))
 
 
 def tracing() -> bool:
@@ -239,6 +318,16 @@ def export(path: str | None = None) -> dict:
 # ---------------------------------------------------------------------------
 # device-profile alignment (jax.profiler / HLO metadata)
 
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``: the span on a profiler
+    trace's host plane.  Called only while tracing."""
+    try:
+        from jax import profiler
+    except ImportError:     # jax absent: host-side span only
+        return _NULL
+    return profiler.TraceAnnotation(name)
+
+
 def named_scope(name: str):
     """Name the ops traced inside the body (HLO op-metadata scope), so a
     device profile (``jax.profiler.trace``) shows the same ring-step /
@@ -258,21 +347,4 @@ def named_scope(name: str):
     stack.enter_context(_RECORDER.span(name, cat="jit-trace",
                                        track="jit-trace"))
     stack.enter_context(scope)
-    return stack
-
-
-def annotation(name: str, **kwargs):
-    """Host-side ``jax.profiler.TraceAnnotation`` (shows up on the device
-    profile's host rows) combined with a span on the default recorder —
-    the glue that lines our timeline up with ``jax.profiler.trace``."""
-    try:
-        from jax.profiler import TraceAnnotation
-        ann = TraceAnnotation(name, **kwargs)
-    except Exception:
-        ann = _NULL
-    if not _RECORDER.enabled:
-        return ann
-    stack = contextlib.ExitStack()
-    stack.enter_context(_RECORDER.span(name, cat="dispatch"))
-    stack.enter_context(ann)
     return stack

@@ -742,31 +742,39 @@ class PagedServeEngine:
             self._count(status.value.lower())
 
     # -- device steps -------------------------------------------------------
-    def _prefill_one_chunk(self, slot: int, stats: ServeStats):
+    def _prefill_one_chunk(self, slot: int, stats: ServeStats, rec) -> int:
+        """Run one prefill chunk of ``slot``'s sequence; returns its
+        tokens."""
         req = self.slots[slot]
         C = self.prefill_chunk
         start = req.prefilled
         chunk = req.seq[start:start + C]
         n = len(chunk)
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :n] = chunk
-        batch = {"tokens": jnp.asarray(toks),
-                 "block_tables": jnp.asarray(self.tables.row(slot)[None]),
-                 "start": jnp.asarray(start, jnp.int32),
-                 "length": jnp.asarray(n, jnp.int32),
-                 "slot": jnp.asarray(slot, jnp.int32)}
-        rec = obs.get_recorder()
+        track = self._req_track(req) if self._observe else "serve"
+        # the chunk's host arrays and their transfers: a span of their
+        # own, since ``prefill_chunk`` starts at the call
+        with rec.span("prefill_build", cat="serve", track=track):
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :n] = chunk
+            batch = {"tokens": jnp.asarray(toks),
+                     "block_tables": jnp.asarray(self.tables.row(slot)[None]),
+                     "start": jnp.asarray(start, jnp.int32),
+                     "length": jnp.asarray(n, jnp.int32),
+                     "slot": jnp.asarray(slot, jnp.int32)}
         t0 = time.time()
-        with rec.span("prefill_chunk", cat="serve",
-                      track=self._req_track(req) if self._observe else "serve",
-                      slot=slot, start=start, tokens=n):
-            logits, self.cache = self._chunk(self.params, self.cache, batch)
-            logits.block_until_ready()
+        with rec.span("prefill_chunk", cat="serve", track=track, slot=slot,
+                      start=start, tokens=n):
+            with rec.span("prefill_dispatch", cat="serve", track=track):
+                logits, self.cache = self._chunk(self.params, self.cache,
+                                                 batch)
+            with rec.span("prefill_wait", cat="serve", track=track):
+                logits.block_until_ready()
         stats.prefill_s += time.time() - t0
         req.prefilled += n
         self.pos[slot] = req.prefilled
         if req.prefilled >= len(req.seq):
             self._last_logits[slot] = logits[0]   # sample at next decode
+        return n
 
     def _sample(self, logits):
         """logits: (V,) or (B, V) -> sampled token id(s), same leading
@@ -803,13 +811,29 @@ class PagedServeEngine:
     def step(self, stats: ServeStats | None = None) -> int:
         """One engine step: expire deadlines, restore preempted lanes,
         admit, advance prefills, decode every running lane, retire
-        finished requests.  Returns tokens emitted."""
+        finished requests.  Returns tokens emitted.
+
+        Traced (``obs``), the step is one ``engine_step`` span on track
+        ``serve`` holding one span per phase, in order: ``admit``,
+        ``prefill_build`` then ``prefill_chunk`` (``prefill_dispatch``,
+        ``prefill_wait``) per chunk, ``first_token``, ``batch_build``,
+        ``decode_step`` (``decode_dispatch``, ``decode_sync``) and
+        ``retire``.  Its args count the step's work from host integers,
+        with no device sync: ``lanes`` decoded and ``prefill_tokens``."""
         stats = stats if stats is not None else ServeStats()
-        if self.chaos is not None:
-            self.chaos.on_admission()
-        self._expire()
-        self._restore_preempted()
-        self._admit()
+        rec = obs.get_recorder()
+        with rec.span("engine_step", cat="serve", track="serve") as counts:
+            if counts is not None:
+                counts.update(lanes=0, prefill_tokens=0)
+            return self._step(stats, rec, counts)
+
+    def _step(self, stats: ServeStats, rec, counts: dict | None) -> int:
+        with rec.span("admit", cat="serve", track="serve"):
+            if self.chaos is not None:
+                self.chaos.on_admission()
+            self._expire()
+            self._restore_preempted()
+            self._admit()
 
         budget = self.prefill_chunks_per_step
         for slot in range(self.max_batch):
@@ -823,12 +847,58 @@ class PagedServeEngine:
             target = min(req.prefilled + self.prefill_chunk, len(req.seq))
             if not self._ensure_blocks(slot, target):
                 continue
-            self._prefill_one_chunk(slot, stats)
+            n = self._prefill_one_chunk(slot, stats, rec)
+            if counts is not None:
+                counts["prefill_tokens"] += n
             budget -= 1
 
-        # sample the first token for lanes whose prefill just completed
-        # (restored recompute lanes skip it — their next token is already
-        # in req.out, re-entering as the decode input below)
+        if self._last_logits:
+            with rec.span("first_token", cat="serve", track="serve"):
+                self._sample_first_tokens()
+
+        with rec.span("batch_build", cat="serve", track="serve"):
+            lanes, batch = self._build_batch()
+        if not lanes:
+            return 0
+        if self.chaos is not None:
+            try:
+                self.chaos.on_decode_step()
+            except ChaosError:
+                # transient device fault BEFORE dispatch: nothing was
+                # mutated, so the identical step re-runs next iteration
+                self._count("decode_faults")
+                return 0
+        if counts is not None:
+            counts["lanes"] = len(lanes)
+        if self._observe:
+            rec.counter("blocks_in_use", self.alloc.in_use, track="serve",
+                        cat="serve")
+        t0 = time.time()
+        with rec.span("decode_step", cat="serve", track="serve",
+                      lanes=len(lanes)):
+            with rec.span("decode_dispatch", cat="serve", track="serve"):
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  batch)
+                sampled = self._sample(logits)
+            with rec.span("decode_sync", cat="serve", track="serve"):
+                nxt = np.asarray(sampled)
+        stats.decode_s += time.time() - t0
+        stats.steps += 1
+
+        with rec.span("retire", cat="serve", track="serve"):
+            for b in lanes:
+                req = self.slots[b]
+                req.out.append(int(nxt[b]))
+                self.pos[b] += 1
+                stats.tokens_out += 1
+                if req.done:
+                    self._finish_slot(b)
+        return len(lanes)
+
+    def _sample_first_tokens(self):
+        """Sample the first token of every lane whose prefill just
+        completed (restored recompute lanes skip it: their next token is
+        already in req.out, re-entering as the decode input)."""
         for slot, logits in list(self._last_logits.items()):
             req = self.slots[slot]
             if req.emit_first:
@@ -840,6 +910,9 @@ class PagedServeEngine:
             if req.done:                      # degenerate 1-token budget
                 self._finish_slot(slot)
 
+    def _build_batch(self):
+        """The lanes that decode this step (their blocks ensured) and the
+        decode batch for them; no lanes, no batch."""
         lanes = []
         for b, r in enumerate(self.slots):
             if r is None or r.prefilled < len(r.seq) or r.done:
@@ -854,7 +927,7 @@ class PagedServeEngine:
         # collected lane — drop lanes whose slot was emptied
         lanes = [b for b in lanes if self.slots[b] is not None]
         if not lanes:
-            return 0
+            return lanes, None
 
         toks = np.zeros((self.max_batch, 1), np.int32)
         tables = np.zeros_like(self.tables.tables)
@@ -870,36 +943,7 @@ class PagedServeEngine:
                  "block_tables": jnp.asarray(tables),
                  "pos": jnp.asarray(pos),
                  "active": jnp.asarray(active)}
-        if self.chaos is not None:
-            try:
-                self.chaos.on_decode_step()
-            except ChaosError:
-                # transient device fault BEFORE dispatch: nothing was
-                # mutated, so the identical step re-runs next iteration
-                self._count("decode_faults")
-                return 0
-        rec = obs.get_recorder()
-        if self._observe:
-            rec.counter("blocks_in_use", self.alloc.in_use, track="serve",
-                        cat="serve")
-            obs.get_metrics().gauge("serve.blocks_in_use").set(
-                self.alloc.in_use)
-        t0 = time.time()
-        with rec.span("decode_step", cat="serve", track="serve",
-                      lanes=len(lanes)):
-            logits, self.cache = self._decode(self.params, self.cache, batch)
-            nxt = np.asarray(self._sample(logits))
-        stats.decode_s += time.time() - t0
-        stats.steps += 1
-
-        for b in lanes:
-            req = self.slots[b]
-            req.out.append(int(nxt[b]))
-            self.pos[b] += 1
-            stats.tokens_out += 1
-            if req.done:
-                self._finish_slot(b)
-        return len(lanes)
+        return lanes, batch
 
     @property
     def busy(self) -> bool:

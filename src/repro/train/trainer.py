@@ -347,7 +347,7 @@ class Trainer:
                 break
             batch = globalize(batch)
             with rec.span("step", cat="train", track="trainer", step=i), \
-                    obs.annotation("train_step"):
+                    rec.span("train_step", cat="train", track="trainer"):
                 if step_fn is not None:
                     params, opt_state, metrics = step_fn(params, opt_state,
                                                          batch)

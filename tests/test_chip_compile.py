@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,11 +61,17 @@ def spec(topo):
     compilation_cache.reset_cache()
 
 
-def _compile(f, *args):
+def _compile(f, *args, kernel=None):
     """Compile for the described chip; the kernel must survive as a
-    Mosaic custom call (not a fallback lowered by XLA)."""
+    Mosaic custom call (not a fallback lowered by XLA), under the name
+    ``kernel`` its ``pallas_call`` gives it when one is asked for: the
+    device trace's readers find it by that name."""
     compiled = jax.jit(f).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel is not None:
+        assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text), \
+            f"no tpu_custom_call named {kernel}"
     return compiled
 
 
@@ -86,7 +93,7 @@ def test_paged_decode_compiles(spec, kv_dtype):
             q, k, v, t, n, k_scale=ks, v_scale=vs, **kw)
     else:
         f = lambda q, k, v, t, n: paged_attention(q, k, v, t, n, **kw)
-    _compile(f, *args)
+    _compile(f, *args, kernel="paged_attention")
 
 
 def test_paged_decode_step_compiles_with_kernel(spec, monkeypatch):
@@ -104,20 +111,22 @@ def test_paged_decode_step_compiles_with_kernel(spec, monkeypatch):
              "block_tables": spec((LANES, PAGES), jnp.int32),
              "pos": spec((LANES,), jnp.int32),
              "active": spec((LANES,), jnp.bool_)}
-    _compile(model.decode_paged, params, cache, batch)
+    _compile(model.decode_paged, params, cache, batch,
+             kernel="paged_attention")
 
 
 def test_flash_attention_compiles(spec):
     qkv = [spec((2, 512, H, HD), jnp.bfloat16)] * 3
     kw = dict(_defaults("flash_attention"), interpret=False)
-    _compile(lambda q, k, v: flash_attention(q, k, v, **kw), *qkv)
+    _compile(lambda q, k, v: flash_attention(q, k, v, **kw), *qkv,
+             kernel="flash_attention")
 
 
 def test_rmsnorm_compiles(spec):
     kw = dict(_defaults("rmsnorm"), interpret=False)
     _compile(lambda x, w: rmsnorm(x, w, **kw),
              spec((2048, CFG.d_model), jnp.bfloat16),
-             spec((CFG.d_model,), jnp.bfloat16))
+             spec((CFG.d_model,), jnp.bfloat16), kernel="rmsnorm")
 
 
 @pytest.mark.parametrize("rows", [1, LANES])   # first token, decode batch
@@ -126,7 +135,7 @@ def test_sampling_compiles(spec, rows):
               top_p=0.9, interpret=False)
     _compile(functools.partial(sample_tokens, **kw),
              spec((rows, CFG.vocab), jnp.float32),
-             spec((rows,), jnp.float32))
+             spec((rows,), jnp.float32), kernel="sample_tokens")
 
 
 def test_fused_sgd_update_compiles(spec):
@@ -134,4 +143,17 @@ def test_fused_sgd_update_compiles(spec):
     shape = (CFG.d_model, CFG.d_ff)
     _compile(functools.partial(sgd_momentum, **kw),
              spec(shape, jnp.bfloat16), spec(shape, jnp.bfloat16),
-             spec(shape, jnp.float32))
+             spec(shape, jnp.float32), kernel="sgd_momentum")
+
+
+def test_sampler_program_name(spec):
+    """The serving engine samples through ``kernels.ops``, whose jitted
+    sampler compiles to its own program: the device trace names it
+    ``jit_sample_tokens``, which the chat cell's sampler reader matches."""
+    from repro.kernels import ops
+    kw = dict(_defaults("sample_tokens"), temperature=0.8, top_k=50,
+              top_p=0.9, interpret=False)
+    compiled = ops._sample_jit.lower(
+        spec((LANES, CFG.vocab), jnp.float32), spec((LANES,), jnp.float32),
+        **kw).compile()
+    assert compiled.as_text().startswith("HloModule jit_sample_tokens,")

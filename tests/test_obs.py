@@ -1,6 +1,8 @@
-"""Observability layer (ISSUE 6 / DESIGN.md §11): trace recorder
-semantics, metrics quantiles, Perfetto export validity, the serving
-engine's per-request lifecycle spans, and engine-stats reset coherence."""
+"""Observability layer (DESIGN.md §11): trace recorder semantics,
+metrics quantiles, Perfetto export validity, the serving engine's
+per-request lifecycle spans and step phases, compile and GC spans, the
+profiler's copy of each span, and engine-stats reset coherence."""
+import gc
 import json
 
 import jax
@@ -104,7 +106,8 @@ def test_enable_starts_fresh_timeline():
         rec = obs.enable()
         with rec.span("x"):
             pass
-        assert len(rec.events()) == 1
+        # an enabled default recorder also records GC spans
+        assert [e["name"] for e in rec.events() if e["name"] != "gc"] == ["x"]
         obs.enable(False)
         rec2 = obs.enable()                     # off -> on: fresh buffer
         assert rec2.events() == []
@@ -241,3 +244,240 @@ def test_warmup_is_not_observed(recorder):
     eng.warmup()
     assert get_metrics().histogram("serve.ttft_s").count == before
     assert eng._observe is True                 # restored after warmup
+
+
+# ---------------------------------------------------------------------------
+# engine step phases and counts, compile and GC spans, the profiler's clock
+
+# the top-level phases of PagedServeEngine.step, in order (a prefill
+# chunk's build and the chunk repeat as a pair), and the children of the
+# two that touch the device
+PHASES = ["admit", "prefill_build", "prefill_chunk", "first_token",
+          "batch_build", "decode_step", "retire"]
+RANK = dict(admit=0, prefill_build=1, prefill_chunk=1,
+            first_token=2, batch_build=3, decode_step=4, retire=5)
+CHILDREN = {"prefill_chunk": ["prefill_dispatch", "prefill_wait"],
+            "decode_step": ["decode_dispatch", "decode_sync"]}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = reduced(get_config("qwen1.5-0.5b"))
+    return cfg, get_model(cfg).init(KEY)
+
+
+def _engine(tiny, max_batch=2):
+    cfg, params = tiny
+    return PagedServeEngine(cfg, params, block_size=8, max_batch=max_batch,
+                            max_len=64, prefill_chunk=8)
+
+
+def _prompts(cfg, lengths=(5, 11, 19, 9)):
+    rng = np.random.RandomState(3)
+    return [list(rng.randint(1, cfg.vocab, n)) for n in lengths]
+
+
+def _inside(outer, events):
+    end = outer["ts"] + outer["dur"]
+    return sorted((e for e in events if e is not outer
+                   and outer["ts"] <= e["ts"] and e["ts"] + e["dur"] <= end),
+                  key=lambda e: e["ts"])
+
+
+def test_engine_step_holds_its_phases_in_order(recorder, tiny):
+    eng = _engine(tiny)
+    eng.warmup()
+    recorder.clear()
+    prompts = _prompts(tiny[0])
+    eng.generate(prompts, max_new_tokens=[3, 4, 6, 2], warmup=False)
+    spans = [e for e in recorder.events() if e["ph"] == "X"]
+    steps = [e for e in spans if e["name"] == "engine_step"]
+    assert steps and len(_tids_for(recorder, "serve")) == 1
+    seen = set()
+    for st in steps:
+        top = [e for e in _inside(st, spans) if e["name"] in PHASES]
+        names = [e["name"] for e in top]
+        assert names[0] == "admit"
+        order = [RANK[n] for n in names]
+        assert order == sorted(order), names
+        # each chunk's host arrays are built just before it runs
+        assert all(names[i - 1] == "prefill_build"
+                   for i, n in enumerate(names) if n == "prefill_chunk")
+        for e in top:
+            if e["name"] in CHILDREN:
+                kids = [k["name"] for k in _inside(e, spans)]
+                assert kids == CHILDREN[e["name"]]
+        seen.update(names)
+    assert seen == set(PHASES)
+    # the two spans the benchmark's readers match keep their tracks, args
+    serve = _tids_for(recorder, "serve")
+    chunks = [e for e in spans if e["name"] == "prefill_chunk"]
+    req_tids = {t for r in eng.results for t in _tids_for(recorder, f"req{r}")}
+    assert {e["tid"] for e in chunks} == req_tids
+    for e in chunks:
+        assert set(e["args"]) == {"slot", "start", "tokens"}
+    decodes = [e for e in spans if e["name"] == "decode_step"]
+    assert decodes and all(e["tid"] in serve and e["args"]["lanes"] >= 1
+                           for e in decodes)
+
+
+def test_engine_step_counts_each_steps_work(recorder, tiny):
+    """Two lanes, chunks of 8, one chunk a step: prompts of 5, 11, 19 and
+    9 tokens for 3, 4, 6 and 2 new ones.  A lane decodes from the step
+    its prefill completes (its first token comes from the prefill), so
+    the counts follow from the schedule."""
+    eng = _engine(tiny)
+    eng.warmup()
+    recorder.clear()
+    eng.generate(_prompts(tiny[0]), max_new_tokens=[3, 4, 6, 2],
+                 warmup=False)
+    counts = [(e["args"]["lanes"], e["args"]["prefill_tokens"])
+              for e in recorder.events() if e["name"] == "engine_step"]
+    assert counts == [
+        (1, 5),     # request 0 prefills whole and decodes
+        (1, 8),     # request 1's first chunk; request 0 ends
+        (0, 8),     # request 2 takes lane 0: its first two chunks
+        (0, 8),
+        (1, 3),     # request 2's last chunk, and it decodes
+        (2, 3),     # request 1's last chunk: both lanes decode
+        (2, 0),
+        (2, 0),     # request 1 ends
+        (1, 8),     # request 3 takes lane 1
+        (1, 1)]     # all four end
+    assert sum(p for _, p in counts) == 5 + 11 + 19 + 9
+    assert sum(n for n, _ in counts) == (3 - 1) + (4 - 1) + (6 - 1) + (2 - 1)
+    assert all(set(e["args"]) == {"lanes", "prefill_tokens"}
+               for e in recorder.events() if e["name"] == "engine_step")
+
+
+def test_disabled_recorder_builds_nothing_and_adds_no_sync(tiny,
+                                                           monkeypatch):
+    """Off, a step constructs no TraceAnnotation and leaves gc.callbacks
+    as it found them; on, it makes exactly the device syncs it makes
+    off (the counts come from host integers)."""
+    import jax.profiler
+    from jax._src import array
+    from repro import obs
+    from repro.obs import trace
+    from repro.serve import engine as engine_mod
+    made, syncs = [], []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    value = array.ArrayImpl._value
+    wait = array.ArrayImpl.block_until_ready
+
+    def counted_wait(self):
+        syncs.append("wait")
+        return wait(self)
+
+    class CountingNumpy:
+        """The engine's ``np``, counting conversions of device arrays (on
+        the CPU they read the buffer directly, past ``_value``)."""
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kw):
+            if isinstance(a, jax.Array):
+                syncs.append("asarray")
+            return np.asarray(a, *args, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    monkeypatch.setattr(array.ArrayImpl, "_value", property(
+        lambda self: (syncs.append("fetch"), value.fget(self))[1]))
+    monkeypatch.setattr(array.ArrayImpl, "block_until_ready", counted_wait)
+    monkeypatch.setattr(engine_mod, "np", CountingNumpy())
+    cfg = tiny[0]
+
+    def run():
+        made.clear()
+        syncs.clear()
+        eng = _engine(tiny)
+        eng.generate(_prompts(cfg), max_new_tokens=[3, 4, 6, 2],
+                     warmup=False)
+        return list(syncs)
+
+    old = get_recorder()
+    callbacks = list(gc.callbacks)
+    try:
+        set_recorder(TraceRecorder(enabled=False))
+        run()                                   # compiles every shape
+        off = run()
+        assert made == [] and gc.callbacks == callbacks
+        obs.enable(True)
+        assert trace._on_gc in gc.callbacks
+        on = run()
+        assert "engine_step" in made and "decode_sync" in made
+        assert on == off
+        assert off.count("wait") > 0 and off.count("asarray") > 0
+        obs.enable(False)
+        assert gc.callbacks == callbacks
+    finally:
+        set_recorder(old)
+    assert gc.callbacks == callbacks
+
+
+def test_compiles_and_collections_become_spans():
+    import jax.numpy as jnp
+    from repro import obs
+    old = get_recorder()
+    try:
+        rec = obs.enable()
+
+        def triple_plus_one(x):
+            return x * 3 + 1
+
+        jax.jit(triple_plus_one)(jnp.ones((7, 13)))    # a fresh program
+        gc.collect()
+        obs.enable(False)
+    finally:
+        set_recorder(old)
+    evs = rec.events()
+    comp = [e for e in evs if e["name"] == "compile"
+            and "triple_plus_one" in e["args"]["fun_name"]]
+    # one XLA compile, besides its tracing and lowering stages
+    stages = [e["args"]["stage"] for e in comp]
+    assert stages.count("backend_compile_duration") == 1
+    assert "jaxpr_trace_duration" in stages
+    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in comp)
+    assert _tids_for(rec, "jit") == {e["tid"] for e in comp}
+    gcs = [e for e in evs if e["name"] == "gc"]
+    assert any(e["args"]["generation"] == 2 for e in gcs)
+    assert _tids_for(rec, "gc") == {e["tid"] for e in gcs}
+    # off again: a compile records nothing
+    n = len(rec.events())
+    jax.jit(lambda x: x - 5)(jnp.ones((3, 17)))
+    assert len(rec.events()) == n
+
+
+def test_profiler_trace_holds_the_span_names(tiny, tmp_path):
+    """Each span is also a TraceAnnotation: a CPU profiler trace holds the
+    step's phases on its host plane, on the device ops' clock."""
+    from jax.profiler import ProfileData
+    from repro import obs
+    eng = _engine(tiny)
+    eng.warmup()
+    old = get_recorder()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    try:
+        obs.enable()
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            eng.generate(_prompts(tiny[0])[:2], max_new_tokens=[3, 2],
+                         warmup=False)
+        finally:
+            jax.profiler.stop_trace()
+            obs.enable(False)
+    finally:
+        set_recorder(old)
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"engine_step", *PHASES, *CHILDREN["prefill_chunk"],
+            *CHILDREN["decode_step"]} <= names
