@@ -62,15 +62,16 @@ def _paged_setup(kv_dtype=None):
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     B, H, K, hd, bs, NB, P = 4, 8, 2, 64, 16, 12, 4
     q = jax.random.normal(ks[0], (B, H, hd))
-    kp = jax.random.normal(ks[1], (NB, bs, K, hd))
-    vp = jax.random.normal(ks[2], (NB, bs, K, hd))
+    kp = jax.random.normal(ks[1], (1, NB, bs, K, hd))
+    vp = jax.random.normal(ks[2], (1, NB, bs, K, hd))
     tables = jnp.arange(1, 1 + B * P, dtype=jnp.int32).reshape(B, P) % NB
     lengths = jnp.asarray([37, 32, 1, 64], jnp.int32)
     kw = {}
     if kv_dtype is not None:
         kp, kw["k_scale"] = kv_quantize_rows(kp, kv_dtype)
         vp, kw["v_scale"] = kv_quantize_rows(vp, kv_dtype)
-    return (q, kp, vp, tables, lengths), kw
+    lanes = (1, NB, bs, K * hd)                  # the stacked pool's rows
+    return (q, kp.reshape(lanes), vp.reshape(lanes), tables, lengths, 0), kw
 
 
 def run(csv=True):

@@ -91,13 +91,13 @@ def _kernel_parity():
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     B, H, K, hd, bs, NB, P = 4, 8, 2, 64, 16, 12, 4
     q = jax.random.normal(ks[0], (B, H, hd))
-    kp = jax.random.normal(ks[1], (NB, bs, K, hd))
-    vp = jax.random.normal(ks[2], (NB, bs, K, hd))
+    kp = jax.random.normal(ks[1], (1, NB, bs, K * hd))
+    vp = jax.random.normal(ks[2], (1, NB, bs, K * hd))
     tables = jnp.arange(1, 1 + B * P, dtype=jnp.int32).reshape(B, P) % NB
     # mid-block, exact boundary, one token, full table
     lengths = jnp.asarray([37, 32, 1, 64], jnp.int32)
-    out = paged_attention(q, kp, vp, tables, lengths)
-    want = ref.paged_attention_ref(q, kp, vp, tables, lengths)
+    out = paged_attention(q, kp, vp, tables, lengths, 0)
+    want = ref.paged_attention_ref(q, kp, vp, tables, lengths, 0)
     return float(jnp.abs(out - want).max())
 
 

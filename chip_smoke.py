@@ -138,19 +138,21 @@ def serve_phase(cfg, seed: int) -> dict:
         jnp.zeros((B, cfg.vocab), jnp.float32), jnp.zeros((B,), jnp.float32)),
         "the sampler does not call the Pallas kernel")
 
-    # the compiled paged kernel against the oracle, on layer 0 of the
-    # engine's pool (K/V the requests wrote) with every block in a table
+    # the compiled paged kernel against the oracle, on the last layer of
+    # the engine's stacked pool (K/V the requests wrote), read as it lies,
+    # with every block in a table
     pool = eng.cache["layers"]["p0"]
-    k_pages, v_pages = pool["k"][0], pool["v"][0]
-    nb = k_pages.shape[0]
+    k_pages, v_pages = pool["k"], pool["v"]      # (L, NB, bs, K*hd)
+    layer = jnp.int32(k_pages.shape[0] - 1)
+    nb = k_pages.shape[1]
     tables = jnp.asarray(rng.permutation(np.arange(1, nb))[:B * P]
                          .reshape(B, P), jnp.int32)
     lengths = jnp.asarray(rng.randint(1, P * BLOCK_SIZE + 1, B), jnp.int32)
     q = jax.random.normal(jax.random.PRNGKey(seed + 1),
                           (B, cfg.n_heads, cfg.hd), k_pages.dtype)
-    out = paged_attention(q, k_pages, v_pages, tables, lengths)
+    out = paged_attention(q, k_pages, v_pages, tables, lengths, layer)
     want = jax.jit(ref.paged_attention_ref)(q, k_pages, v_pages, tables,
-                                            lengths)
+                                            lengths, layer)
     want = want.astype(jnp.float32)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)))
     bound = PAGED_REL_ERR * max(1.0, float(jnp.max(jnp.abs(want))))

@@ -52,15 +52,16 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
                       return_carry=return_carry, interpret=interpret, **p)
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
+def paged_attention(q, k_pages, v_pages, block_tables, lengths, layer, *,
                     k_scale=None, v_scale=None, window=None, softcap=None,
                     pages_per_step=None, head_tile=None, interpret=None):
     p = registry.resolve(
         "paged_attention",
         {"pages_per_step": pages_per_step, "head_tile": head_tile},
         registry.get("paged_attention").bucket_of(
-            q, k_pages, v_pages, block_tables, lengths, k_scale=k_scale))
-    return _paged_jit(q, k_pages, v_pages, block_tables, lengths,
+            q, k_pages, v_pages, block_tables, lengths, layer,
+            k_scale=k_scale))
+    return _paged_jit(q, k_pages, v_pages, block_tables, lengths, layer,
                       k_scale=k_scale, v_scale=v_scale, window=window,
                       softcap=softcap, interpret=interpret, **p)
 

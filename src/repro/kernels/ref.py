@@ -31,29 +31,33 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None, softcap=None,
     return jnp.einsum("bhqs,bshd->bqhd", p, vv).astype(q.dtype)
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
-                        k_scale=None, v_scale=None, window=None,
+def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, layer,
+                        *, k_scale=None, v_scale=None, window=None,
                         softcap=None):
-    """q: (B, H, hd); pools: (NB, bs, K, hd); block_tables: (B, P) int32;
-    lengths: (B,) live tokens incl. the current one.  Gathers the logical
-    KV through the table, then masked dense attention in f32.  This is
-    also the CPU fast path the serving engine uses (interpret-mode Pallas
-    is per-grid-step Python).
+    """q: (B, H, hd); pools: (L, NB, bs, K*hd) stacked lane-dense;
+    block_tables: (B, P) int32; lengths: (B,) live tokens incl. the
+    current one; layer: which of the L layers to read.  Gathers the
+    layer's logical KV through the table (only the table's pages, never
+    the whole layer), then masked dense attention in f32.  This is also
+    the CPU fast path the serving engine uses (interpret-mode Pallas is
+    per-grid-step Python).
 
-    ``k_scale``/``v_scale``: (NB, bs, K) f32 per-(token, kv-head) scales
-    for quantized pools (DESIGN.md §13) — rows dequantize as
+    ``k_scale``/``v_scale``: (L, NB, bs, K) f32 per-(token, kv-head)
+    scales for quantized pools (DESIGN.md §13) — rows dequantize as
     ``row.astype(f32) * scale`` before attention."""
     B, H, hd = q.shape
-    NB, bs, K, _ = k_pages.shape
+    bs = k_pages.shape[2]
+    K = k_pages.shape[3] // hd
     G = H // K
     P = block_tables.shape[1]
-    # (B, P, bs, K, hd) -> (B, P*bs, K, hd): logical position order
-    k = k_pages[block_tables].reshape(B, P * bs, K, hd)
-    v = v_pages[block_tables].reshape(B, P * bs, K, hd)
+    # (B, P, bs, K*hd) -> (B, P*bs, K, hd): logical position order
+    k = k_pages[layer, block_tables].reshape(B, P * bs, K, hd)
+    v = v_pages[layer, block_tables].reshape(B, P * bs, K, hd)
     if k_scale is not None:
         from .quant import kv_dequantize
-        k = kv_dequantize(k, k_scale[block_tables].reshape(B, P * bs, K))
-        v = kv_dequantize(v, v_scale[block_tables].reshape(B, P * bs, K))
+        ks = k_scale[layer, block_tables].reshape(B, P * bs, K)
+        vs = v_scale[layer, block_tables].reshape(B, P * bs, K)
+        k, v = kv_dequantize(k, ks), kv_dequantize(v, vs)
     qg = q.reshape(B, K, G, hd)
     s = jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) / np.sqrt(hd)

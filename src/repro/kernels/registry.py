@@ -150,20 +150,21 @@ def _register_all():
         bench_cases=(("S256_gqa", flash_case(1, 256, 4, 2, 64)),
                      ("S512_gqa", flash_case(1, 512, 8, 2, 64)))))
 
-    def paged_bucket(q, k_pages, v_pages, block_tables, lengths, **kw):
+    def paged_bucket(q, k_pages, v_pages, block_tables, lengths, layer,
+                     **kw):
         B, H, hd = q.shape
-        bs, K = k_pages.shape[1], k_pages.shape[2]
+        bs, K = k_pages.shape[2], k_pages.shape[3] // hd
         P = block_tables.shape[1]
         quant = "q" if kw.get("k_scale") is not None else ""
         return (f"B={pow2_bucket(B)},P={pow2_bucket(P)},bs={bs},H={H},"
                 f"K={K},hd={hd},{_dt(k_pages.dtype)}{quant}")
 
-    def paged_case(B, P, NB, bs, H, K, hd, kv_dtype=None):
+    def paged_case(B, P, NB, bs, H, K, hd, L=2, kv_dtype=None):
         def make():
             import jax
             import numpy as np
-            kp = _rand(1, (NB, bs, K, hd))
-            vp = _rand(2, (NB, bs, K, hd))
+            kp = _rand(1, (L, NB, bs, K, hd))
+            vp = _rand(2, (L, NB, bs, K, hd))
             kw = {}
             if kv_dtype is not None:
                 from .quant import kv_quantize_rows
@@ -173,7 +174,10 @@ def _register_all():
                 jax.random.PRNGKey(3),
                 np.arange(1, NB))[:B * P].reshape(B, P).astype(jnp.int32)
             lengths = jnp.full((B,), P * bs - bs // 2, jnp.int32)
-            return ((_rand(0, (B, H, hd)), kp, vp, tables, lengths), kw)
+            lanes = (L, NB, bs, K * hd)
+            return ((_rand(0, (B, H, hd)), kp.reshape(lanes),
+                     vp.reshape(lanes), tables, lengths,
+                     jnp.int32(L - 1)), kw)
         return make
 
     register(OpSpec(

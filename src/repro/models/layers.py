@@ -251,28 +251,29 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, softcap=None):
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
-                           k_scale=None, v_scale=None, window=None,
+def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
+                           layer, *, k_scale=None, v_scale=None, window=None,
                            softcap=None):
-    """One-token attention through the paged pool (DESIGN.md §9).
+    """One-token attention through layer ``layer`` of the stacked paged
+    pool (DESIGN.md §9).
 
-    q: (B, H, hd); pools: (NB, bs, K, hd); block_tables: (B, P);
-    lengths: (B,) live tokens including the current one.  Runs the
+    q: (B, H, hd); pools: (L, NB, bs, K*hd) lane-dense; block_tables:
+    (B, P); lengths: (B,) live tokens including the current one.  Runs the
     compiled Pallas paged kernel when the backend is a TPU; elsewhere the
     gather-based oracle is the fast path (interpret-mode Pallas runs the
     grid in Python).
-    ``k_scale``/``v_scale``: (NB, bs, K) f32 per-row scales when the
+    ``k_scale``/``v_scale``: (L, NB, bs, K) f32 per-row scales when the
     pools are quantized (DESIGN.md §13); both paths fuse the dequant into
     attention — no full-precision cache copy.
     """
     if jax.default_backend() == "tpu":
         from repro.kernels.ops import paged_attention
         return paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                               k_scale=k_scale, v_scale=v_scale,
+                               layer, k_scale=k_scale, v_scale=v_scale,
                                window=window, softcap=softcap)
     from repro.kernels.ref import paged_attention_ref
     return paged_attention_ref(q, k_pages, v_pages, block_tables, lengths,
-                               k_scale=k_scale, v_scale=v_scale,
+                               layer, k_scale=k_scale, v_scale=v_scale,
                                window=window, softcap=softcap)
 
 
@@ -409,45 +410,70 @@ def attn_block_decode(p, x, cache_k, cache_v, pos, cfg, spec):
     return out, cache_k, cache_v
 
 
-def attn_block_decode_paged(p, x, cache, block_tables, pos, cfg, spec):
-    """Single-token decode through the paged pool. x: (B, 1, D); cache:
-    layer dict with "k"/"v" (NB, bs, K, hd) pools (plus "k_scale"/
-    "v_scale" (NB, bs, K) f32 when the pools are quantized, DESIGN.md
-    §13); block_tables: (B, P); pos: (B,) absolute position of the
-    incoming token.  Writes the token's k/v into its block-table slot
-    (quantizing on append), then attends through the table.  Returns
-    (out, new_cache).  Inactive lanes must carry sink tables (pos 0,
-    table 0) so their writes land in the sink block."""
-    k_pages, v_pages = cache["k"], cache["v"]
-    quantized = "k_scale" in cache
+def paged_append(pools, layer, page, off, k, v):
+    """Write new K/V rows into layer ``layer`` of the stacked paged pools,
+    in place: ``k``/``v`` (N, K, hd) land at rows ``(layer, page[n],
+    off[n])`` of the (L, NB, bs, K*hd) pools, quantized on append when the
+    pools carry "k_scale"/"v_scale" (L, NB, bs, K) (DESIGN.md §13: the row
+    and its per-(token, kv-head) scale land together).  Only those N rows
+    change; returns the updated pools dict."""
+    N = k.shape[0]
+    new = dict(pools)
+    if "k_scale" in pools:
+        from repro.kernels.quant import kv_quantize_rows
+        k, ks = kv_quantize_rows(k, pools["k"].dtype)
+        v, vs = kv_quantize_rows(v, pools["v"].dtype)
+        new["k_scale"] = pools["k_scale"].at[layer, page, off].set(ks)
+        new["v_scale"] = pools["v_scale"].at[layer, page, off].set(vs)
+    new["k"] = pools["k"].at[layer, page, off].set(
+        k.reshape(N, -1).astype(pools["k"].dtype))
+    new["v"] = pools["v"].at[layer, page, off].set(
+        v.reshape(N, -1).astype(pools["v"].dtype))
+    return new
+
+
+def paged_gather(pools, layer, table, n_kv_heads):
+    """One sequence's logical context out of layer ``layer`` of the stacked
+    paged pools: the P blocks of ``table`` (P,), gathered alone (never the
+    whole layer) and laid end to end, as (1, P*bs, K, hd) k and v,
+    dequantized to f32 when the pools carry scales."""
+    _, _, bs, lanes = pools["k"].shape
+    P = table.shape[0]
+    shape = (1, P * bs, n_kv_heads, lanes // n_kv_heads)
+    k = pools["k"][layer, table].reshape(shape)
+    v = pools["v"][layer, table].reshape(shape)
+    if "k_scale" in pools:
+        from repro.kernels.quant import kv_dequantize
+        k = kv_dequantize(k, pools["k_scale"][layer, table].reshape(shape[:3]))
+        v = kv_dequantize(v, pools["v_scale"][layer, table].reshape(shape[:3]))
+    return k, v
+
+
+def attn_block_decode_paged(p, x, pools, layer, block_tables, pos, cfg,
+                            spec):
+    """Single-token decode through the paged pool. x: (B, 1, D); pools:
+    dict with the stacked "k"/"v" (L, NB, bs, K*hd) pools (plus "k_scale"/
+    "v_scale" (L, NB, bs, K) f32 when quantized, DESIGN.md §13); layer:
+    this block's index into them; block_tables: (B, P); pos: (B,) absolute
+    position of the incoming token.  Writes the token's k/v rows into
+    their block-table slots of layer ``layer`` (quantizing on append),
+    then attends through the table.  Returns (out, new_pools).  Inactive
+    lanes must carry sink tables (pos 0, table 0) so their writes land in
+    the sink block."""
     q, k, v = attn_project_qkv(p, x, cfg)
     cos, sin = rope_freqs(pos[:, None], cfg.hd, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    NB, bs, K, hd = k_pages.shape
+    bs = pools["k"].shape[2]
     B = q.shape[0]
     page = block_tables[jnp.arange(B), pos // bs]        # physical block
-    idx = page * bs + pos % bs
-    k_row, v_row = k[:, 0], v[:, 0]                      # (B, K, hd)
-    scales = {}
-    if quantized:
-        from repro.kernels.quant import kv_quantize_rows
-        k_row, ks_row = kv_quantize_rows(k_row, k_pages.dtype)
-        v_row, vs_row = kv_quantize_rows(v_row, v_pages.dtype)
-        scales = {
-            "k_scale": cache["k_scale"].reshape(NB * bs, K).at[idx].set(
-                ks_row).reshape(NB, bs, K),
-            "v_scale": cache["v_scale"].reshape(NB * bs, K).at[idx].set(
-                vs_row).reshape(NB, bs, K)}
-    k_pages = k_pages.reshape(NB * bs, K, hd).at[idx].set(
-        k_row.astype(k_pages.dtype)).reshape(NB, bs, K, hd)
-    v_pages = v_pages.reshape(NB * bs, K, hd).at[idx].set(
-        v_row.astype(v_pages.dtype)).reshape(NB, bs, K, hd)
-    out = paged_decode_attention(q[:, 0], k_pages, v_pages, block_tables,
-                                 pos + 1, window=spec.window,
-                                 softcap=cfg.attn_softcap, **scales)
+    pools = paged_append(pools, layer, page, pos % bs, k[:, 0], v[:, 0])
+    out = paged_decode_attention(
+        q[:, 0], pools["k"], pools["v"], block_tables, pos + 1, layer,
+        k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
+        window=spec.window, softcap=cfg.attn_softcap)
     out = jnp.einsum("bshk,hkd->bsd", out[:, None], p["wo"])
-    return out, {"k": k_pages, "v": v_pages, **scales}
+    return out, pools
 
 
 def cross_attn_block(p, x, enc_kv, cfg):

@@ -24,8 +24,8 @@ from repro.dist.annotate import BATCH, ann
 from .common import ArchConfig, LayerSpec
 from .layers import (attn_block, attn_block_decode, attn_block_decode_paged,
                      attn_project_qkv, apply_rope, cross_attn_block,
-                     mlp_block, paged_context_attention,
-                     rmsnorm, rope_freqs)
+                     mlp_block, paged_append, paged_context_attention,
+                     paged_gather, rmsnorm, rope_freqs)
 from .moe import moe_block
 from .ssm import mamba_block
 
@@ -198,16 +198,18 @@ def apply_block(p, x, cfg: ArchConfig, spec: LayerSpec, enc_kv=None,
 
 
 def apply_block_decode(p, x, cache, pos, cfg: ArchConfig, spec: LayerSpec,
-                       enc_kv=None, block_tables=None, active=None):
+                       enc_kv=None, block_tables=None, active=None,
+                       layer=None):
     """One-token block step.  ``block_tables`` switches attention layers to
-    the paged pool (cache["k"]/["v"] are then (NB, bs, K, hd) pools and
-    ``pos`` is the (B,) per-sequence position vector).  ``active``: (B,)
-    bool — lanes that are NOT decoding this step (empty slots, requests
-    still mid-prefill) keep their recurrent SSM states untouched; their
+    the paged pool (cache is then the stacked pools dict, "k"/"v" (L, NB,
+    bs, K*hd), of which this block is layer ``layer``, and ``pos`` is the
+    (B,) per-sequence position vector).  ``active``: (B,) bool — lanes
+    that are NOT decoding this step (empty slots, requests still
+    mid-prefill) keep their recurrent SSM states untouched; their
     attention writes already land in the sink block."""
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if spec.kind == "attn" and block_tables is not None:
-        h, new_cache = attn_block_decode_paged(p["attn"], h, cache,
+        h, new_cache = attn_block_decode_paged(p["attn"], h, cache, layer,
                                                block_tables, pos, cfg, spec)
     elif spec.kind == "attn":
         h, ck, cv = attn_block_decode(p["attn"], h, cache["k"], cache["v"],
@@ -572,17 +574,21 @@ def prefill(params, batch, cfg: ArchConfig, pad_to: int | None = None):
     return logits[:, 0], {"layers": caches, **extra, "pos": pos}
 
 
-def _stack_step(cfg, body, x, xs):
-    """Run ``body`` over the super-block stack (unrolled <=4 for exact
-    cost_analysis, ``lax.scan`` else), stacking the per-super-block cache
-    outputs — the shared dispatch of every decode/prefill step."""
+def _stack_step(cfg, body, carry, xs):
+    """Run ``body(carry, xs_i) -> (carry, y)`` over the super-block stack
+    (unrolled <=4 for exact cost_analysis, ``lax.scan`` else), stacking
+    the per-super-block outputs ``y`` — the shared dispatch of every
+    decode/prefill step.  ``xs_i["layer"]`` is the super-block's index,
+    a Python int when unrolled, so a body can address the stacked pools
+    it carries."""
+    xs = {**xs, "layer": np.arange(cfg.n_super, dtype=np.int32)}
     if cfg.n_super <= 4:
         ys = []
         for i in range(cfg.n_super):
-            x, y = body(x, jax.tree.map(lambda a: a[i], xs))
+            carry, y = body(carry, jax.tree.map(lambda a: a[i], xs))
             ys.append(y)
-        return x, jax.tree.map(lambda *a: jnp.stack(a), *ys)
-    return jax.lax.scan(body, x, xs)
+        return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+    return jax.lax.scan(body, carry, xs)
 
 
 def decode_step(params, cache, batch, cfg: ArchConfig):
@@ -662,10 +668,14 @@ def make_cache(cfg: ArchConfig, batch: int, seq_len: int, enc_len: int = 0):
 
 def make_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
                      max_batch: int, kv_dtype=None):
-    """Zeroed paged cache: per attention pattern-position a physical block
-    pool (n_super, num_blocks, block_size, K, hd); SSM layers keep per-slot
-    recurrent states (their footprint is position-independent — nothing to
-    page).  Block 0 is the sink (``serve.paging.SINK_BLOCK``).
+    """Zeroed paged cache: per attention pattern-position one stacked,
+    lane-dense block pool (n_super, num_blocks, block_size, K*hd) for k
+    and one for v, a row holding one token's K heads side by side; SSM
+    layers keep per-slot recurrent states (their footprint is
+    position-independent — nothing to page).  Block 0 is the sink
+    (``serve.paging.SINK_BLOCK``).  The serving steps carry the pools
+    through the layer scan and write only their new rows, and the paged
+    kernel reads a layer of them as they lie (DESIGN.md §9).
 
     ``kv_dtype``: None/"native" stores KV in the activation dtype;
     "int8"/"fp8_e4m3"/"fp8_e5m2" store quantized rows plus per-(token,
@@ -682,11 +692,9 @@ def make_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
     for i, spec in enumerate(cfg.pattern):
         n = cfg.n_super
         if spec.kind == "attn":
-            layers[f"p{i}"] = {
-                "k": jnp.zeros((n, num_blocks, block_size, K, hd),
-                               qdt or dt),
-                "v": jnp.zeros((n, num_blocks, block_size, K, hd),
-                               qdt or dt)}
+            rows = (n, num_blocks, block_size, K * hd)
+            layers[f"p{i}"] = {"k": jnp.zeros(rows, qdt or dt),
+                               "v": jnp.zeros(rows, qdt or dt)}
             if qdt is not None:
                 layers[f"p{i}"]["k_scale"] = jnp.zeros(
                     (n, num_blocks, block_size, K), jnp.float32)
@@ -699,6 +707,17 @@ def make_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
                 "ssm": jnp.zeros((n, max_batch, cfg.ssm_heads, cfg.ssm_p,
                                   cfg.ssm_state), jnp.float32)}
     return {"layers": layers}
+
+
+def _split_paged(cfg: ArchConfig, layers):
+    """The paged cache's layers as (pools, states): the attention
+    positions' stacked KV pools, which the layer scan carries and updates
+    in place, and the SSM positions' per-slot states, small enough to
+    ride the scan as xs/ys."""
+    pools = {f"p{i}": layers[f"p{i}"] for i, spec in enumerate(cfg.pattern)
+             if spec.kind == "attn"}
+    states = {name: c for name, c in layers.items() if name not in pools}
+    return pools, states
 
 
 def paged_swap_out(cache, slot: int, block_ids) -> dict:
@@ -750,81 +769,69 @@ def decode_step_paged(params, cache, batch, cfg: ArchConfig):
     position per lane (0 for inactive lanes, whose writes land in the
     sink block); active (B,) bool — lanes decoding this step (inactive
     lanes' SSM states are preserved).  Returns (logits (B, V), new_cache).
+    The KV pools ride the layer scan's carry: each layer writes its B new
+    rows in place and the kernel reads the layer where it lies.
     """
     tokens, tables, pos = batch["tokens"], batch["block_tables"], batch["pos"]
     active = batch["active"]
     x = embed_tokens(params, tokens, cfg)
     pattern = cfg.pattern
 
-    def body(x, xs):
-        bp, layer_cache = xs["params"], xs["cache"]
-        new_caches = {}
+    def body(carry, xs):
+        x, pools = carry
+        pools = dict(pools)
+        bp, states, layer = xs["params"], xs["states"], xs["layer"]
+        new_states = {}
         for i, spec in enumerate(pattern):
-            x, nc = apply_block_decode(bp[f"p{i}"], x, layer_cache[f"p{i}"],
-                                       pos, cfg, spec, block_tables=tables,
-                                       active=active)
-            new_caches[f"p{i}"] = nc
-        return x, new_caches
+            name = f"p{i}"
+            if name in pools:
+                x, pools[name] = apply_block_decode(
+                    bp[name], x, pools[name], pos, cfg, spec,
+                    block_tables=tables, layer=layer)
+            else:
+                x, new_states[name] = apply_block_decode(
+                    bp[name], x, states[name], pos, cfg, spec, active=active)
+        return (x, pools), new_states
 
-    xs = {"params": params["blocks"], "cache": cache["layers"]}
-    x, new_layers = _stack_step(cfg, body, x, xs)
+    pools, states = _split_paged(cfg, cache["layers"])
+    (x, pools), states = _stack_step(
+        cfg, body, (x, pools), {"params": params["blocks"], "states": states})
     logits = final_logits(params, x[:, -1:], cfg)
-    return logits[:, 0], {**cache, "layers": new_layers}
+    return logits[:, 0], {**cache, "layers": {**pools, **states}}
 
 
 def _apply_block_prefill_paged(p, x, layer_cache, cfg, spec, *, tables,
-                               start, length, slot, positions):
-    """One block of a paged prefill chunk.  x: (1, C, D).  Writes the
-    chunk's K/V rows through the (1, P) block table (pad rows -> sink),
-    attends against the gathered logical context, and threads the slot's
-    SSM states.  Returns (x, new_layer_cache)."""
+                               start, length, slot, positions, layer):
+    """One block of a paged prefill chunk.  x: (1, C, D).  An attention
+    block writes the chunk's K/V rows into layer ``layer`` of the stacked
+    pools (``layer_cache``) through the (1, P) block table (pad rows ->
+    sink) and attends against its own P pages gathered back; an SSM block
+    threads the slot's states.  Returns (x, new_layer_cache)."""
     C = x.shape[1]
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if spec.kind == "attn":
-        k_pool, v_pool = layer_cache["k"], layer_cache["v"]
-        quantized = "k_scale" in layer_cache
-        NB, bs, K, hd = k_pool.shape
+        pools = layer_cache
+        bs = pools["k"].shape[2]
         P = tables.shape[1]
         q, k, v = attn_project_qkv(p["attn"], h, cfg)
         cos, sin = rope_freqs(positions, cfg.hd, cfg.rope_theta)
         q = apply_rope(q, cos[None], sin[None])
         k = apply_rope(k, cos[None], sin[None])
-        j = jnp.arange(C)
-        page = jnp.clip(positions // bs, 0, P - 1)
-        idx = jnp.where(j < length,
-                        tables[0, page] * bs + positions % bs, 0)
-        k_rows, v_rows = k[0], v[0]                       # (C, K, hd)
-        scales = {}
-        if quantized:
-            # quantize on append (DESIGN.md §13): the pool row and its
-            # per-(token, kv-head) scale land together; pad rows (idx 0)
-            # write garbage into the sink block, masked out by kv_len
-            from repro.kernels.quant import kv_dequantize, kv_quantize_rows
-            k_rows, ks_rows = kv_quantize_rows(k_rows, k_pool.dtype)
-            v_rows, vs_rows = kv_quantize_rows(v_rows, v_pool.dtype)
-            scales = {
-                "k_scale": layer_cache["k_scale"].reshape(NB * bs, K)
-                .at[idx].set(ks_rows).reshape(NB, bs, K),
-                "v_scale": layer_cache["v_scale"].reshape(NB * bs, K)
-                .at[idx].set(vs_rows).reshape(NB, bs, K)}
-        k_pool = k_pool.reshape(NB * bs, K, hd).at[idx].set(
-            k_rows.astype(k_pool.dtype)).reshape(NB, bs, K, hd)
-        v_pool = v_pool.reshape(NB * bs, K, hd).at[idx].set(
-            v_rows.astype(v_pool.dtype)).reshape(NB, bs, K, hd)
-        # gather the logical context (chunk rows included) and attend
-        ctx_k = k_pool[tables[0]].reshape(1, P * bs, K, hd)
-        ctx_v = v_pool[tables[0]].reshape(1, P * bs, K, hd)
-        if quantized:
-            ctx_k = kv_dequantize(
-                ctx_k, scales["k_scale"][tables[0]].reshape(1, P * bs, K))
-            ctx_v = kv_dequantize(
-                ctx_v, scales["v_scale"][tables[0]].reshape(1, P * bs, K))
+        # pad rows write garbage into the sink block's row 0, masked out
+        # by kv_len
+        real = jnp.arange(C) < length
+        page = tables[0, jnp.clip(positions // bs, 0, P - 1)]
+        page = jnp.where(real, page, 0)
+        off = jnp.where(real, positions % bs, 0)
+        pools = paged_append(pools, layer, page, off, k[0], v[0])
+        # attend to the logical context, the chunk's own rows included
+        ctx_k, ctx_v = paged_gather(pools, layer, tables[0], cfg.n_kv_heads)
         h = paged_context_attention(q, ctx_k, ctx_v, q_offset=start,
                                     kv_len=start + length,
                                     window=spec.window,
                                     softcap=cfg.attn_softcap)
         h = jnp.einsum("bshk,hkd->bsd", h, p["attn"]["wo"])
-        new_cache = {"k": k_pool, "v": v_pool, **scales}
+        new_cache = pools
     else:
         conv_all, ssm_all = layer_cache["conv"], layer_cache["ssm"]
         conv0 = jax.lax.dynamic_slice_in_dim(conv_all, slot, 1, axis=0)
@@ -871,19 +878,26 @@ def prefill_chunk_paged(params, cache, batch, cfg: ArchConfig):
     positions = start + jnp.arange(C)
     pattern = cfg.pattern
 
-    def body(x, xs):
-        bp, layer_cache = xs["params"], xs["cache"]
-        new_caches = {}
+    def body(carry, xs):
+        x, pools = carry
+        pools = dict(pools)
+        bp, states, layer = xs["params"], xs["states"], xs["layer"]
+        new_states = {}
         for i, spec in enumerate(pattern):
+            name = f"p{i}"
             x, nc = _apply_block_prefill_paged(
-                bp[f"p{i}"], x, layer_cache[f"p{i}"], cfg, spec,
-                tables=tables, start=start, length=length, slot=slot,
-                positions=positions)
-            new_caches[f"p{i}"] = nc
-        return x, new_caches
+                bp[name], x, pools[name] if name in pools else states[name],
+                cfg, spec, tables=tables, start=start, length=length,
+                slot=slot, positions=positions, layer=layer)
+            if name in pools:
+                pools[name] = nc
+            else:
+                new_states[name] = nc
+        return (x, pools), new_states
 
-    xs = {"params": params["blocks"], "cache": cache["layers"]}
-    x, new_layers = _stack_step(cfg, body, x, xs)
+    pools, states = _split_paged(cfg, cache["layers"])
+    (x, pools), states = _stack_step(
+        cfg, body, (x, pools), {"params": params["blocks"], "states": states})
     x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     logits = final_logits(params, x_last, cfg)
-    return logits[:, 0], {**cache, "layers": new_layers}
+    return logits[:, 0], {**cache, "layers": {**pools, **states}}

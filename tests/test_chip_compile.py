@@ -83,17 +83,26 @@ def _defaults(op):
 def test_paged_decode_compiles(spec, kv_dtype):
     nb = LANES * PAGES + 1
     kd = jnp.dtype(kv_dtype)
-    args = [spec((LANES, H, HD), jnp.bfloat16),
-            spec((nb, BLOCK, K, HD), kd), spec((nb, BLOCK, K, HD), kd),
-            spec((LANES, PAGES), jnp.int32), spec((LANES,), jnp.int32)]
+    pool = (2, nb, BLOCK, K * HD)                # two stacked layers
+    args = [spec((LANES, H, HD), jnp.bfloat16), spec(pool, kd),
+            spec(pool, kd), spec((LANES, PAGES), jnp.int32),
+            spec((LANES,), jnp.int32), spec((), jnp.int32)]
     kw = dict(_defaults("paged_attention"), interpret=False)
     if kd == jnp.int8:
-        args += [spec((nb, BLOCK, K), jnp.float32)] * 2
-        f = lambda q, k, v, t, n, ks, vs: paged_attention(
-            q, k, v, t, n, k_scale=ks, v_scale=vs, **kw)
+        args += [spec((2, nb, BLOCK, K), jnp.float32)] * 2
+        f = lambda q, k, v, t, n, l, ks, vs: paged_attention(
+            q, k, v, t, n, l, k_scale=ks, v_scale=vs, **kw)
     else:
-        f = lambda q, k, v, t, n: paged_attention(q, k, v, t, n, **kw)
+        f = lambda q, k, v, t, n, l: paged_attention(q, k, v, t, n, l, **kw)
     _compile(f, *args, kernel="paged_attention")
+
+
+def _paged_step_args(spec, model, blocks, lanes, pages):
+    place = lambda tree: jax.tree.map(lambda s: spec(s.shape, s.dtype), tree)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: model.make_paged_cache(blocks, BLOCK, lanes)))
+    return params, cache
 
 
 def test_paged_decode_step_compiles_with_kernel(spec, monkeypatch):
@@ -103,16 +112,59 @@ def test_paged_decode_step_compiles_with_kernel(spec, monkeypatch):
     from repro.models import get_model
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = get_model(replace(CFG, n_layers=2))
-    place = lambda tree: jax.tree.map(lambda s: spec(s.shape, s.dtype), tree)
-    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = place(jax.eval_shape(
-        lambda: model.make_paged_cache(LANES * PAGES + 1, BLOCK, LANES)))
+    params, cache = _paged_step_args(spec, model, LANES * PAGES + 1, LANES,
+                                     PAGES)
     batch = {"tokens": spec((LANES, 1), jnp.int32),
              "block_tables": spec((LANES, PAGES), jnp.int32),
              "pos": spec((LANES,), jnp.int32),
              "active": spec((LANES,), jnp.bool_)}
     _compile(model.decode_paged, params, cache, batch,
              kernel="paged_attention")
+
+
+_POOL_OPS = re.compile(r"= \w+\[([\d,]*)\]\S* "
+                       r"(copy|copy-start|dynamic-slice|dynamic-update-slice)\(")
+
+
+@pytest.mark.parametrize("arch,layers,blocks,lanes", [
+    ("qwen1.5-0.5b", 24, 3840, 64),    # the chat cell: K 16, hd 64
+    ("starcoder2-15b", 10, 8193, 32),  # the backlog cell: K 4, hd 128
+])
+def test_paged_steps_update_pool_in_place(spec, monkeypatch, arch, layers,
+                                          blocks, lanes):
+    """Both serving steps, as a serving cell runs them (its layers under
+    the layer scan, its whole pool), update the donated stacked pool in
+    place: no copy, slice or update-slice of an array holding the pool's
+    block count anywhere in the compiled program, the pool aliased from
+    input to output, and temporaries under a quarter of the pool.  A
+    (bs, K, hd) pool tile made XLA copy each layer's pool out, re-lay it
+    out for the kernel and back, and write it into a second stacked pool;
+    at 24 layers its temporaries outgrew the pool.  The scan keeps each
+    compile to a few seconds."""
+    from dataclasses import replace
+    from repro.models import get_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pages, chunk = 256, 512
+    model = get_model(replace(get_config(arch), n_layers=layers))
+    params, cache = _paged_step_args(spec, model, blocks, lanes, pages)
+    pool = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    i32 = lambda *shape: spec(shape, jnp.int32)
+    steps = {
+        "decode_paged": {"tokens": i32(lanes, 1),
+                         "block_tables": i32(lanes, pages), "pos": i32(lanes),
+                         "active": spec((lanes,), jnp.bool_)},
+        "prefill_chunk_paged": {"tokens": i32(1, chunk),
+                                "block_tables": i32(1, pages), "start": i32(),
+                                "length": i32(), "slot": i32()}}
+    for name, batch in steps.items():
+        compiled = jax.jit(getattr(model, name), donate_argnums=(1,)).lower(
+            params, cache, batch).compile()
+        whole = [m.group(0) for m in _POOL_OPS.finditer(compiled.as_text())
+                 if str(blocks) in m.group(1).split(",")]
+        assert not whole, (name, whole[:4])
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= pool, (name, mem)
+        assert mem.temp_size_in_bytes < pool / 4, (name, mem)
 
 
 def test_flash_attention_compiles(spec):

@@ -196,11 +196,19 @@ def test_flash_attention_kv_len_masking():
 # ---------------------------------------------------------------------------
 # paged attention (ISSUE 4, DESIGN.md §9)
 
-def _paged_case(B, H, K, hd, bs, NB, P, lengths, seed=5):
+def _lanes(pool):
+    """(..., K, hd) rows -> the lane-dense (..., K*hd) rows the stacked
+    pool stores."""
+    return pool.reshape(*pool.shape[:-2], -1)
+
+
+def _paged_case(B, H, K, hd, bs, NB, P, lengths, seed=5, L=1):
+    """q, stacked lane-dense pools (L, NB, bs, K*hd) with distinct random
+    layers, block tables and lengths."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, H, hd))
-    kp = jax.random.normal(ks[1], (NB, bs, K, hd))
-    vp = jax.random.normal(ks[2], (NB, bs, K, hd))
+    kp = jax.random.normal(ks[1], (L, NB, bs, K * hd))
+    vp = jax.random.normal(ks[2], (L, NB, bs, K * hd))
     # distinct physical blocks per (seq, page), none using the sink 0
     tables = (1 + jnp.arange(B * P, dtype=jnp.int32) % (NB - 1)).reshape(B, P)
     return q, kp, vp, tables, jnp.asarray(lengths, jnp.int32)
@@ -211,8 +219,8 @@ def test_paged_attention_gqa_vs_ref(H, K):
     from repro.kernels.paged_attention import paged_attention
     q, kp, vp, tables, lengths = _paged_case(
         B=3, H=H, K=K, hd=32, bs=8, NB=16, P=4, lengths=[19, 8, 1])
-    out = paged_attention(q, kp, vp, tables, lengths)
-    want = ref.paged_attention_ref(q, kp, vp, tables, lengths)
+    out = paged_attention(q, kp, vp, tables, lengths, 0)
+    want = ref.paged_attention_ref(q, kp, vp, tables, lengths, 0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -224,8 +232,8 @@ def test_paged_attention_block_boundaries(lengths):
     from repro.kernels.paged_attention import paged_attention
     q, kp, vp, tables, lengths = _paged_case(
         B=4, H=4, K=2, hd=64, bs=8, NB=24, P=5, lengths=lengths)
-    out = paged_attention(q, kp, vp, tables, lengths)
-    want = ref.paged_attention_ref(q, kp, vp, tables, lengths)
+    out = paged_attention(q, kp, vp, tables, lengths, 0)
+    want = ref.paged_attention_ref(q, kp, vp, tables, lengths, 0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -237,12 +245,46 @@ def test_paged_attention_window_softcap(window, softcap):
     q, kp, vp, tables, lengths = _paged_case(
         B=2, H=4, K=2, hd=32, bs=8, NB=12, P=3, lengths=[21, 13])
     q = q * 3                                   # exercise the softcap
-    out = paged_attention(q, kp, vp, tables, lengths, window=window,
+    out = paged_attention(q, kp, vp, tables, lengths, 0, window=window,
                           softcap=softcap)
-    want = ref.paged_attention_ref(q, kp, vp, tables, lengths,
+    want = ref.paged_attention_ref(q, kp, vp, tables, lengths, 0,
                                    window=window, softcap=softcap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("H,K,hd", [(16, 16, 64),     # qwen1.5-0.5b heads
+                                    (48, 4, 128)])    # starcoder2, G 12
+@pytest.mark.parametrize("variant", ["plain", "window", "softcap", "int8"])
+def test_paged_attention_stacked_layer_vs_ref(layer, H, K, hd, variant):
+    """The kernel reads layer ``layer`` of a three-layer stacked pool as
+    it lies, cutting its heads out of the lane-dense rows: it matches the
+    oracle on the stacked pool, and the oracle matches itself on that one
+    layer alone (so neither reads another layer)."""
+    from repro.kernels.paged_attention import paged_attention
+    q, kp, vp, tables, lengths = _paged_case(
+        B=2, H=H, K=K, hd=hd, bs=8, NB=7, P=3, lengths=[21, 9], L=3)
+    kw = {"window": 10} if variant == "window" else \
+        {"softcap": 20.0} if variant == "softcap" else {}
+    if variant == "softcap":
+        q = q * 3
+    if variant == "int8":
+        from repro.kernels.quant import kv_quantize_rows
+        split = (3, 7, 8, K, hd)
+        kp, kw["k_scale"] = kv_quantize_rows(kp.reshape(split), jnp.int8)
+        vp, kw["v_scale"] = kv_quantize_rows(vp.reshape(split), jnp.int8)
+        kp, vp = _lanes(kp), _lanes(vp)
+    out = paged_attention(q, kp, vp, tables, lengths, layer, **kw)
+    want = ref.paged_attention_ref(q, kp, vp, tables, lengths, layer, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=3e-5, atol=3e-5)
+    one = slice(layer, layer + 1)
+    alone = {k: (v[one] if k.endswith("scale") else v)
+             for k, v in kw.items()}
+    want1 = ref.paged_attention_ref(q, kp[one], vp[one], tables, lengths, 0,
+                                    **alone)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(want1))
 
 
 def test_paged_attention_matches_contiguous_flash():
@@ -258,17 +300,18 @@ def test_paged_attention_matches_contiguous_flash():
     want = ref.flash_attention_ref(q1, k, v, causal=True, q_offset=S - 1)
     # scatter the contiguous rows into shuffled physical blocks
     order = np.asarray([3, 1, 4, 2])            # physical block per page
-    kp = np.zeros((6, bs, K, hd), np.float32)
-    vp = np.zeros((6, bs, K, hd), np.float32)
+    kp = np.zeros((1, 6, bs, K, hd), np.float32)
+    vp = np.zeros((1, 6, bs, K, hd), np.float32)
     for page in range(P):
         rows = np.asarray(k[0, page * bs:(page + 1) * bs])
-        kp[order[page], :rows.shape[0]] = rows
+        kp[0, order[page], :rows.shape[0]] = rows
         rows = np.asarray(v[0, page * bs:(page + 1) * bs])
-        vp[order[page], :rows.shape[0]] = rows
+        vp[0, order[page], :rows.shape[0]] = rows
     from repro.kernels.paged_attention import paged_attention
-    out = paged_attention(q1[:, 0], jnp.asarray(kp), jnp.asarray(vp),
+    out = paged_attention(q1[:, 0], jnp.asarray(_lanes(kp)),
+                          jnp.asarray(_lanes(vp)),
                           jnp.asarray(order[None], jnp.int32),
-                          jnp.asarray([S], jnp.int32))
+                          jnp.asarray([S], jnp.int32), 0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want[:, 0]),
                                rtol=2e-5, atol=2e-5)
 
@@ -278,9 +321,85 @@ def test_paged_attention_zero_length_lane_is_zero():
     q, kp, vp, tables, _ = _paged_case(
         B=2, H=4, K=2, hd=32, bs=8, NB=12, P=3, lengths=[5, 0])
     out = paged_attention(q, kp, vp, tables,
-                          jnp.asarray([5, 0], jnp.int32))
+                          jnp.asarray([5, 0], jnp.int32), 0)
     assert np.abs(np.asarray(out[1])).max() == 0.0
     assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+@pytest.mark.parametrize("n_layers", [2, 6])           # unrolled, lax.scan
+def test_paged_step_writes_only_its_rows(step, kv_dtype, n_layers):
+    """One serving step leaves every row of every layer of the stacked
+    pools bitwise as it was, except the rows it wrote at (layer, block,
+    offset), and each of those holds that layer's own K/V of its token.
+    The blocks' output projections are zeroed, so every layer sees the
+    token's embedding and its rows can be computed alone; a row written
+    into another layer, block or offset fails one of the two checks."""
+    from repro.configs import get_config
+    from repro.models import get_model, layers, reduced, transformer
+    from repro.kernels.quant import kv_dequantize
+    cfg = reduced(get_config("qwen1.5-0.5b"), n_layers=n_layers)
+    model = get_model(cfg)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: jnp.zeros_like(a)
+        if path[-1].key in ("wo", "wd") else a, model.init(KEY))
+    cache = model.make_paged_cache(9, 4, 3, kv_dtype=kv_dtype)
+    keys = iter(jax.random.split(KEY, 16))
+    noise = lambda a: (jax.random.normal(next(keys), a.shape) * 4).astype(
+        a.dtype)                      # every row distinct from a new one
+    cache = jax.tree.map(noise, cache)
+    if step == "decode":
+        # lane 0 at position 5 (its page 1, block 2, offset 1), lane 1 at
+        # 2 (block 3, offset 2), lane 2 idle on the sink
+        batch = {"tokens": jnp.asarray([[7], [8], [0]], jnp.int32),
+                 "block_tables": jnp.asarray([[1, 2], [3, 4], [0, 0]],
+                                             jnp.int32),
+                 "pos": jnp.asarray([5, 2, 0], jnp.int32),
+                 "active": jnp.asarray([True, True, False])}
+        written = {(2, 1): (7, 5), (3, 2): (8, 2)}   # row: (token, pos)
+        fn = model.decode_paged
+    else:
+        # positions 2..4 of the slot's blocks (1, 2), one pad row -> sink
+        batch = {"tokens": jnp.asarray([[5, 6, 7, 0]], jnp.int32),
+                 "block_tables": jnp.asarray([[1, 2]], jnp.int32),
+                 "start": jnp.asarray(2, jnp.int32),
+                 "length": jnp.asarray(3, jnp.int32),
+                 "slot": jnp.asarray(0, jnp.int32)}
+        written = {(1, 2): (5, 2), (1, 3): (6, 3), (2, 0): (7, 4)}
+        fn = model.prefill_chunk_paged
+    _, new = jax.jit(fn)(params, cache, batch)
+    old, got = cache["layers"]["p0"], new["layers"]["p0"]
+    for key in old:
+        a, b = np.asarray(old[key]), np.asarray(got[key])
+        assert b.shape == a.shape == (n_layers, 9, 4) + a.shape[3:]
+        same = (a == b).reshape(n_layers, 9, 4, -1).all(-1)
+        for blk, off in written:
+            same[:, blk, off] = True
+        same[:, 0, 0] = True           # the sink takes idle and pad rows
+        assert same.all(), (key, np.argwhere(~same))
+
+    def own_rows(layer, token, pos):
+        bp = jax.tree.map(lambda a: a[layer], params["blocks"])["p0"]
+        x = transformer.embed_tokens(params, jnp.asarray([[token]]), cfg)
+        _, k, v = layers.attn_project_qkv(
+            bp["attn"], layers.rmsnorm(x, bp["ln1"], cfg.norm_eps), cfg)
+        cos, sin = layers.rope_freqs(jnp.asarray([[pos]]), cfg.hd,
+                                     cfg.rope_theta)
+        return {"k": layers.apply_rope(k, cos, sin)[0, 0], "v": v[0, 0]}
+
+    for layer in range(n_layers):
+        for (blk, off), (token, pos) in written.items():
+            want = own_rows(layer, token, pos)
+            for kv in ("k", "v"):
+                row = got[kv][layer, blk, off].reshape(want[kv].shape)
+                step_ = 1e-5
+                if kv_dtype is not None:
+                    scale = got[f"{kv}_scale"][layer, blk, off]
+                    row, step_ = kv_dequantize(row, scale), float(scale.max())
+                np.testing.assert_allclose(np.asarray(row),
+                                           np.asarray(want[kv]),
+                                           rtol=1e-5, atol=step_)
 
 
 # ---------------------------------------------------------------------------
@@ -293,9 +412,9 @@ def test_paged_attention_schedule_tunables(pps, ht):
     from repro.kernels.paged_attention import paged_attention
     q, kp, vp, tables, lengths = _paged_case(
         B=3, H=16, K=16, hd=32, bs=8, NB=17, P=5, lengths=[19, 33, 40])
-    out = paged_attention(q, kp, vp, tables, lengths,
+    out = paged_attention(q, kp, vp, tables, lengths, 0,
                           pages_per_step=pps, head_tile=ht)
-    want = ref.paged_attention_ref(q, kp, vp, tables, lengths)
+    want = ref.paged_attention_ref(q, kp, vp, tables, lengths, 0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -304,11 +423,14 @@ def test_paged_attention_schedule_tunables(pps, ht):
 # quantized paged KV-cache (int8 / fp8, DESIGN.md §13)
 
 def _quantize_case(kv_dtype, **kw):
+    """A paged case with its pools quantized per (token, kv-head): codes
+    back in the lane-dense rows, scales (L, NB, bs, K)."""
     from repro.kernels.quant import kv_quantize_rows
     q, kp, vp, tables, lengths = _paged_case(**kw)
-    kq, ks = kv_quantize_rows(kp, kv_dtype)
-    vq, vs = kv_quantize_rows(vp, kv_dtype)
-    return q, (kp, vp), (kq, vq, ks, vs), tables, lengths
+    split = (*kp.shape[:3], kw["K"], kw["hd"])
+    kq, ks = kv_quantize_rows(kp.reshape(split), kv_dtype)
+    vq, vs = kv_quantize_rows(vp.reshape(split), kv_dtype)
+    return q, (kp, vp), (_lanes(kq), _lanes(vq), ks, vs), tables, lengths
 
 
 @pytest.mark.parametrize("kv_dtype,fp_tol", [
@@ -322,13 +444,13 @@ def test_paged_attention_quantized(kv_dtype, fp_tol):
     q, (kp, vp), (kq, vq, ks, vs), tables, lengths = _quantize_case(
         resolve_kv_dtype(kv_dtype),
         B=3, H=4, K=2, hd=64, bs=8, NB=16, P=4, lengths=[19, 8, 31])
-    out = paged_attention(q, kq, vq, tables, lengths,
+    out = paged_attention(q, kq, vq, tables, lengths, 0,
                           k_scale=ks, v_scale=vs)
-    qref = ref.paged_attention_ref(q, kq, vq, tables, lengths,
+    qref = ref.paged_attention_ref(q, kq, vq, tables, lengths, 0,
                                    k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(qref),
                                rtol=2e-5, atol=2e-5)
-    fpref = ref.paged_attention_ref(q, kp, vp, tables, lengths)
+    fpref = ref.paged_attention_ref(q, kp, vp, tables, lengths, 0)
     assert np.abs(np.asarray(out) - np.asarray(fpref)).max() < fp_tol
 
 
@@ -336,9 +458,9 @@ def test_paged_attention_quantized_with_schedule_and_window():
     from repro.kernels.paged_attention import paged_attention
     q, _, (kq, vq, ks, vs), tables, lengths = _quantize_case(
         jnp.int8, B=2, H=4, K=2, hd=32, bs=8, NB=12, P=3, lengths=[21, 13])
-    want = ref.paged_attention_ref(q, kq, vq, tables, lengths,
+    want = ref.paged_attention_ref(q, kq, vq, tables, lengths, 0,
                                    k_scale=ks, v_scale=vs, window=6)
-    out = paged_attention(q, kq, vq, tables, lengths, k_scale=ks,
+    out = paged_attention(q, kq, vq, tables, lengths, 0, k_scale=ks,
                           v_scale=vs, window=6, pages_per_step=2,
                           head_tile=2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
