@@ -6,7 +6,7 @@ cell run (set-up, a short window, the check), so set-up is paid once per
 seed and compilation once.  The benchmark's own runs never run this.
 
     python3 bench/calibrate.py --workload <name> --seeds 1,2,3 \
-        --seconds 8 [--control] [--fault token|unchanged|half]
+        --seconds 8 [--control] [--fault token|unchanged|half|exchange]
 
 Prints one JSON line per seed, then the largest reading of each number
 and, with ``--control``, the control's smallest and whether the control
@@ -32,29 +32,60 @@ def token_fault(engine):
     engine._sample = lambda logits: (sample(logits) + 1) % vocab
 
 
-def unchanged_fault(step):
+def unchanged_fault(trainer, mesh):
     """A step that returns its state unchanged."""
+    step = trainer._make_step()
+
     def faulty(params, opt, batch):
         return params, opt, step(params, opt, batch)[2]
     return faulty
 
 
-def half_fault(step):
+def half_fault(trainer, mesh):
     """Half of the batch left out, the mean taken over the rest."""
+    step = trainer._make_step()
+
     def faulty(params, opt, batch):
         toks = batch["tokens"]
         return step(params, opt, {"tokens": toks[:toks.shape[0] // 2]})
     return faulty
 
 
+def exchange_fault(trainer, mesh):
+    """The exchange between chips left out: each chip takes the gradient
+    of its own rows alone and steps its own copy of the state with it, so
+    the copies drift apart (the harness reads chip 0's)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from repro.dist import annotate
+    if mesh is None or mesh.size < 2:
+        raise common.BenchError("the exchange fault needs several chips")
+    if trainer.tcfg.grad_clip:
+        raise common.BenchError("the exchange fault does not clip")
+    model, opt, schedule = trainer.model, trainer.optimizer, trainer.schedule
+
+    def local(params, opt_state, batch):
+        with annotate.suppressed():
+            (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(
+                params, batch)
+        params, opt_state = opt.update(grads, opt_state, params,
+                                       lr_scale=schedule(opt_state["step"]))
+        return params, opt_state, {"loss": loss}
+
+    rows = P(tuple(mesh.axis_names))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P(), rows),
+                                 out_specs=(P(), P(), P()), check_vma=False))
+
+
 FAULTS = {"token": token_fault, "unchanged": unchanged_fault,
-          "half": half_fault}
+          "half": half_fault, "exchange": exchange_fault}
 
 
 def calibrate(cell, seeds, seconds, control, fault, device, clock=None):
     from bench import serve_driver, train_driver
     clock = clock or common.Clock()
-    cfg = common.arch_config(cell.config)
+    cfg = common.arch_config(cell.config, cell.reference)
     driver = train_driver if cell.mix["kind"] == "train" else serve_driver
     rows = []
     for seed in seeds:

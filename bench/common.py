@@ -1,18 +1,25 @@
 """What every cell of the chip benchmark shares: the checkout's layout,
-the cell's data files found by name, the device and its peaks, the
-compile cache, the weights made from the seed, and the result line.
+the cell's data files and reference module found by name, the device and
+its peaks, the compile cache, the weights made from the seed, and the
+result line.
 
 Nothing here imports the system under test at module level; the drivers
-import it once the device has been checked.
+import it once the device has been checked.  Nothing here names an
+architecture either: what is particular to one (its sizes, its weight
+rule, its per-token counts, the file keys the program takes) lies in the
+reference module its configuration file names.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import re
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -35,6 +42,30 @@ def log(**record) -> None:
     print(json.dumps(record, default=float), flush=True)
 
 
+def load_module(path: Path, prefix: str) -> ModuleType:
+    """A module of the benchmark's data (a reader, a reference), loaded by
+    path under a name of its own."""
+    if not path.is_file():
+        raise BenchError(f"no file {path}")
+    name = prefix + re.sub(r"[^A-Za-z0-9_]", "_", path.stem)
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_reference(name: str, root: Path = ROOT) -> ModuleType:
+    """The plain reference a configuration file names under
+    ``"reference"``: ``<root>/bench/reference/<name>.py``.  It gives
+    ``dims_of``, ``logits_at``, ``loss_and_grad``, ``leaf_rule``,
+    ``PROGRAM_KEYS`` and the per-token counts ``matmul_flops_per_token``,
+    ``attn_layers`` and ``kv_bytes_per_token``."""
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", str(name)):
+        raise BenchError(f"reference {name!r} is not a module name")
+    return load_module(root / "bench" / "reference" / f"{name}.py",
+                       "bench_reference_")
+
+
 # ---------------------------------------------------------------------------
 # the cell, found by name
 
@@ -44,13 +75,22 @@ class Cell:
     name: str
     chips: int
     config: dict          # bench/configs/<config>.json
+    reference: ModuleType  # bench/reference/<config's "reference">.py
     mix: dict             # bench/mixes/<traffic>.json
     limits: dict          # bench/limits/<workload>.json
     end_to_end: list      # BENCHMARK.json metrics this cell reports
     per_layer: list
 
+    @property
+    def dims(self) -> dict:
+        """The sizes the reference and the counters read, from the
+        configuration file."""
+        return self.reference.dims_of(self.config)
+
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
+    """The cell ``name`` of ``<root>/BENCHMARK.json`` with its files, all
+    found under ``<root>/bench`` by the names there."""
     bench = load_json(root / "BENCHMARK.json")
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
@@ -64,10 +104,14 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if ("workloads" in m and name in m["workloads"])
                  or ("workloads" not in m and m["moves"] in names)]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=load_json(root / cfg_entry["file"]),
-                mix=load_json(BENCH / "mixes" / f"{w['traffic']}.json"),
-                limits=load_json(BENCH / "limits" / f"{name}.json"),
+    config = load_json(root / cfg_entry["file"])
+    if "reference" not in config:
+        raise BenchError(f"{cfg_entry['file']} names no reference module")
+    return Cell(name=name, chips=int(w["chips"]), config=config,
+                reference=load_reference(config["reference"], root),
+                mix=load_json(root / "bench" / "mixes"
+                              / f"{w['traffic']}.json"),
+                limits=load_json(root / "bench" / "limits" / f"{name}.json"),
                 end_to_end=e2e, per_layer=per_layer)
 
 
@@ -129,56 +173,81 @@ def use_src_path(root: Path = ROOT) -> None:
 # ---------------------------------------------------------------------------
 # model configuration, checked against the file that states it
 
-# the source's config.json keys -> the repo's ArchConfig fields
-HF_KEYS = {
+# keys of a file's ``model`` block that every architecture has -> the
+# repo's ArchConfig fields; the reference module adds its own
+# (``PROGRAM_KEYS``)
+COMMON_KEYS = {
     "num_hidden_layers": "n_layers",
     "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
     "vocab_size": "vocab",
-    "head_dim": "head_dim",
-    "rope_theta": "rope_theta",
     "tie_word_embeddings": "tie_embeddings",
-    "qkv_bias": "qkv_bias",
     "dtype": "dtype",
     "norm_eps": "norm_eps",
 }
 
 
-def arch_config(conf: dict):
+def arch_config(conf: dict, ref: ModuleType):
     """The repo's ArchConfig for a configuration file: the repo's own
     config with every key of the file's ``model`` block that the program
-    takes applied, then checked key by key, so the file states what
-    runs."""
+    takes (``COMMON_KEYS`` and the reference's ``PROGRAM_KEYS``) applied,
+    then checked key by key, so the file states what runs.  The cache's
+    bytes the file states are checked against the program's shapes."""
     from repro.configs import get_config
+    keys = {**COMMON_KEYS, **ref.PROGRAM_KEYS}
     cfg = get_config(conf["arch"])
     model = conf["model"]
-    over = {HF_KEYS[k]: model[k] for k in model if k in HF_KEYS}
+    over = {keys[k]: model[k] for k in model if k in keys}
     cfg = dataclasses.replace(cfg, **over)
-    for key, field in HF_KEYS.items():
+    for key, field in keys.items():
         if key not in model:
             continue
         have = getattr(cfg, field) if field != "head_dim" else cfg.hd
         if have != model[key]:
             raise BenchError(f"{conf['name']}: {key}={model[key]} in the "
                              f"file but {field}={have} in the program")
-    mlp = cfg.pattern[0].mlp
-    if model.get("mlp") != mlp:
-        raise BenchError(f"{conf['name']}: mlp {model.get('mlp')!r} in the "
-                         f"file but {mlp!r} in the program")
+    if "mlp" in model:
+        mlps = sorted({spec.mlp for spec in cfg.pattern})
+        if mlps != [model["mlp"]]:
+            raise BenchError(f"{conf['name']}: mlp {model['mlp']!r} in the "
+                             f"file but {mlps} in the program")
+    pool = conf["kv_pool"]
     per_token = kv_bytes_per_token(cfg)
-    if conf["kv_pool"]["bytes_per_token"] != per_token:
+    if pool["bytes_per_token"] != per_token:
         raise BenchError(f"{conf['name']}: kv bytes/token "
-                         f"{conf['kv_pool']['bytes_per_token']} in the file, "
+                         f"{pool['bytes_per_token']} in the file, "
                          f"{per_token} from the shapes")
+    per_lane = state_bytes_per_lane(cfg)
+    if per_lane or "state_bytes_per_lane" in pool:
+        if pool.get("state_bytes_per_lane") != per_lane:
+            raise BenchError(f"{conf['name']}: state bytes/lane "
+                             f"{pool.get('state_bytes_per_lane')} in the "
+                             f"file, {per_lane} from the program's slots")
     return cfg
 
 
 def kv_bytes_per_token(cfg) -> int:
-    """layers x (k, v) x kv heads x head_dim x bytes of the served dtype."""
+    """Attention layers x (k, v) x kv heads x head_dim x bytes of the
+    served dtype; other layers keep nothing per token."""
     item = 2 if cfg.dtype == "bfloat16" else 4
-    return cfg.n_layers * 2 * cfg.n_kv_heads * cfg.hd * item
+    attn = sum(spec.kind == "attn" for spec in cfg.pattern) * cfg.n_super
+    return attn * 2 * cfg.n_kv_heads * cfg.hd * item
+
+
+def state_bytes_per_lane(cfg) -> int:
+    """Bytes of one lane's recurrent state (conv and SSM) in the program's
+    paged cache: the slot arrays of its non-attention layers, per slot."""
+    kinds = [spec.kind for spec in cfg.pattern]
+    if all(k == "attn" for k in kinds):
+        return 0
+    import jax
+    from repro.models import get_model
+    lanes = 2
+    cache = jax.eval_shape(
+        lambda: get_model(cfg).make_paged_cache(2, 16, lanes))["layers"]
+    total = sum(x.size * x.dtype.itemsize
+                for i, k in enumerate(kinds) if k != "attn"
+                for x in jax.tree.leaves(cache[f"p{i}"]))
+    return total // lanes
 
 
 # ---------------------------------------------------------------------------
@@ -192,39 +261,33 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
 
 
-def _leaf_scale(name: str, cfg) -> float:
-    """Standard deviation of a leaf's random values.  Matrices use
-    1/sqrt(fan-in), so activations keep unit scale through the stack;
-    norm offsets and biases are small but nonzero, so their paths are
-    exercised and checked."""
-    D, F = cfg.d_model, cfg.d_ff
-    table = {"embed": 0.02, "lm_head": D ** -0.5,
-             "wq": D ** -0.5, "wk": D ** -0.5, "wv": D ** -0.5,
-             "wo": (cfg.n_heads * cfg.hd) ** -0.5,
-             "wg": D ** -0.5, "wu": D ** -0.5, "wd": F ** -0.5,
-             "bq": 0.1, "bk": 0.1, "bv": 0.1,
-             "ln1": 0.1, "ln2": 0.1, "final_norm": 0.1}
-    if name not in table:
-        raise BenchError(f"no weight rule for parameter leaf {name!r}")
-    return table[name]
+def leaf_path(path) -> tuple:
+    """A parameter leaf's whole path as a tuple of names:
+    ``('blocks', 'p0', 'attn', 'wq')``."""
+    return tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
 
 
-def make_params(cfg, seed: int, sharding=None):
+def make_params(cfg, seed: int, ref: ModuleType, d: dict, sharding=None):
     """The program's parameter tree, filled from ``seed`` on the device in
-    the dtype it is served in."""
+    the dtype it is served in.  Each leaf is drawn normal with the mean
+    and standard deviation that the reference's ``leaf_rule`` gives for
+    its whole path; one key per leaf, split in the tree's order."""
     import jax
     import jax.numpy as jnp
     from repro.models import get_model
     shapes = jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0))
     paths, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    names = [str(getattr(p[-1], "key", p[-1])) for p, _ in paths]
     specs = [s for _, s in paths]
+    rules = [ref.leaf_rule(leaf_path(p), s.shape, d) for p, s in paths]
 
     def fill(key):
         keys = jax.random.split(key, len(specs))
-        leaves = [(jax.random.normal(k, s.shape, jnp.float32)
-                   * _leaf_scale(n, cfg)).astype(s.dtype)
-                  for k, n, s in zip(keys, names, specs)]
+        leaves = []
+        for k, (mean, std), s in zip(keys, rules, specs):
+            x = jax.random.normal(k, s.shape, jnp.float32) * std
+            if mean:
+                x = x + mean
+            leaves.append(x.astype(s.dtype))
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     kw = {} if sharding is None else {"out_shardings": sharding}

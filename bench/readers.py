@@ -45,11 +45,12 @@ def serve_mfu(ctx):
     steps = ctx.steps_in_window()
     if not steps:
         return None
-    d = ctx.dims
+    ref, d = ctx.cell.reference, ctx.dims
     flops = 0.0
     for st in steps:
-        flops += sum(counters.prefill_flops(d, a, n) for a, n in st.prefill)
-        flops += sum(counters.decode_flops(d, c) for c in st.decode_ctx)
+        flops += sum(counters.prefill_flops(ref, d, a, n)
+                     for a, n in st.prefill)
+        flops += sum(counters.decode_flops(ref, d, c) for c in st.decode_ctx)
     peak = ctx.peaks["bf16_flops_per_s"] * ctx.chips
     return {"value": 100.0 * flops / (_window_s(ctx) * peak)}
 
@@ -64,7 +65,7 @@ def train_mfu(ctx):
         return None
     seq = ctx.cell.mix["seq"]
     flops = n * tr["tokens_per_step"] * counters.train_flops_per_token(
-        ctx.dims, seq)
+        ctx.cell.reference, ctx.dims, seq)
     peak = ctx.peaks["bf16_flops_per_s"] * ctx.chips
     return {"value": 100.0 * flops / (_window_s(ctx) * peak)}
 
@@ -89,7 +90,8 @@ def paged_attn_roofline(ctx, mark: str):
     p = ctx.peaks
     least, mem_bound = 0.0, 0.0
     for st in steps:
-        f, b = counters.paged_attn_cost(ctx.dims, st.decode_ctx)
+        f, b = counters.paged_attn_cost(ctx.cell.reference, ctx.dims,
+                                        st.decode_ctx)
         tf, tb = f / p["bf16_flops_per_s"], b / p["hbm_bytes_per_s"]
         least += max(tf, tb)
         mem_bound += tb >= tf
@@ -116,3 +118,23 @@ def device_idle_share(ctx):
     if busy <= 0:
         return None
     return {"value": 100.0 * (1.0 - busy / ((t1 - t0) / 1e9))}
+
+
+def exposed_collective_ms(ctx):
+    """Collective time during which no compute op runs on the same chip
+    (``trace_reduce.exposed_collective_s``, averaged over the chips), per
+    training step of the window, in ms; beside it the collectives' whole
+    time per step and how many collective ops the window holds by name.
+    A window with no collective op by name reads as nothing, so 0 is a
+    reading and never a missed name."""
+    n = ctx.run["train"]["steps"]
+    t0, t1 = ctx.dev_window
+    coll = trace_reduce.op_seconds(ctx.trace, t0, t1,
+                                   match=trace_reduce.is_collective)
+    if not n or not coll:
+        return None
+    chips = max(len(ctx.trace.devices), 1)
+    exposed = trace_reduce.exposed_collective_s(ctx.trace, t0, t1)
+    return {"value": 1e3 * exposed / n,
+            "collective_ms_per_step": 1e3 * sum(coll.values()) / chips / n,
+            "collective_ops": len(coll)}

@@ -1,5 +1,6 @@
 """The comparisons that decide ``correct``, each against the plain
-reference in ``decoder.py``.
+reference ``ref`` that the cell's configuration names (a module under
+``bench/reference/``, reached through the cell).
 
 Serving: for a sample of the requests the window finished, the reference
 runs once over each prompt with its served tokens, and the number is the
@@ -14,15 +15,15 @@ read the same way.
 Training: each of the first steps' losses, the first gradient as the
 optimizer gets it (read from the momentum after one step), and the
 parameters' change after the steps, each per leaf against the reference
-running the configuration's SGD-momentum update in f32.
+running the configuration's SGD-momentum update in f32.  On several
+chips the reference's rows are split evenly over them and the means
+averaged, so it takes as long as on one chip with its share of the rows.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from . import decoder
 
 SEQ_BUCKET = 1024       # reference sequences padded to a multiple of this
 
@@ -61,8 +62,8 @@ def _pad_to(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def serve_gaps(params, samples, d, sampling, *, max_out: int, seed: int,
-               control: bool = False):
+def serve_gaps(ref, params, samples, d, sampling, *, max_out: int,
+               seed: int, control: bool = False):
     """Gaps of every served token of ``samples`` (a list of (prompt,
     served tokens)): the widest (``logit_gap``) and the mean over tokens
     (``mean_logit_gap``), and with ``control`` the fp8 control's.
@@ -73,7 +74,7 @@ def serve_gaps(params, samples, d, sampling, *, max_out: int, seed: int,
     p = float(p if p is not None else 1.0)
 
     def run(quant):
-        return jax.jit(lambda pr, t, r: decoder.logits_at(pr, t, r, d, quant))
+        return jax.jit(lambda pr, t, r: ref.logits_at(pr, t, r, d, quant))
 
     ref_f, ctl_f = run(None), (run("fp8") if control else None)
     rng = np.random.default_rng(seed)
@@ -87,18 +88,18 @@ def serve_gaps(params, samples, d, sampling, *, max_out: int, seed: int,
         rows = np.full(max_out, len(prompt) - 1 + n - 1, np.int32)
         rows[:n] = len(prompt) - 1 + np.arange(n)
         served = jnp.asarray(np.asarray(out, np.int32))
-        ref = ref_f(params, jnp.asarray(toks), jnp.asarray(rows))[:n]
-        floor = _kept_floor(ref, T, k, p)
-        got = jnp.take_along_axis(ref, served[:, None], -1)[:, 0]
+        want = ref_f(params, jnp.asarray(toks), jnp.asarray(rows))[:n]
+        floor = _kept_floor(want, T, k, p)
+        got = jnp.take_along_axis(want, served[:, None], -1)[:, 0]
         prog.append(np.asarray(jnp.maximum(floor - got, 0.0)))
         if control:
             c = ctl_f(params, jnp.asarray(toks), jnp.asarray(rows))[:n]
             u = jnp.asarray(rng.random(n), jnp.float32)
             pick = _control_pick(c, u, T, k, p)
-            cg = jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
+            cg = jnp.take_along_axis(want, pick[:, None], -1)[:, 0]
             ctl.append(np.asarray(jnp.maximum(floor - cg, 0.0)))
             del c
-        del ref
+        del want
 
     def stats(gaps):
         g = np.concatenate(gaps) if gaps else np.zeros(0)
@@ -123,15 +124,44 @@ def lr_scale(step: int, tr: dict) -> float:
     return ff + (1 - ff) * 0.5 * (1 + np.cos(np.pi * t))
 
 
-def reference_steps(params, batches, d, tr: dict, quant=None):
+def _loss_and_grad(ref, d, quant, mesh):
+    """The reference's mean loss over a batch and its gradient, from the
+    stored parameters upcast to f32; over a mesh of several chips each
+    takes an equal block of the rows and the means are averaged."""
+    def lg(p, t):
+        return ref.loss_and_grad(
+            jax.tree.map(lambda a: a.astype(jnp.float32), p), t, d, quant)
+
+    if mesh is None:
+        return jax.jit(lg)
+    from jax.sharding import PartitionSpec as P
+    n = mesh.size
+
+    def rows(p, t):
+        loss, g = lg(p, t)
+        return (jax.lax.psum(loss, "rows") / n,
+                jax.tree.map(lambda x: jax.lax.psum(x, "rows") / n, g))
+
+    # the reference's own scans carry unvarying zeros: no replication check
+    return jax.jit(jax.shard_map(rows, mesh=mesh, in_specs=(P(), P("rows")),
+                                 out_specs=(P(), P()), check_vma=False))
+
+
+def reference_steps(ref, params, batches, d, tr: dict, quant=None,
+                    devices=None):
     """Run the configuration's SGD-momentum steps from ``params``:
     gradients and update in f32, momentum kept in f32, parameters stored
     between steps in their configured dtype (bf16), as the configuration
-    states.  Returns (losses, first-step gradient norms per leaf,
+    states.  With several ``devices`` the rows of each batch are split
+    over them.  Returns (losses, first-step gradient norms per leaf,
     parameter-change norms per leaf after the steps)."""
     lr, mu, wd = tr["lr"], tr["momentum"], tr["weight_decay"]
-    lg = jax.jit(lambda p, t: decoder.loss_and_grad(
-        jax.tree.map(lambda a: a.astype(jnp.float32), p), t, d, quant))
+    mesh = None
+    if devices is not None and len(devices) > 1:
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        mesh = Mesh(np.asarray(devices), ("rows",))
+        params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
+    lg = _loss_and_grad(ref, d, quant, mesh)
 
     @jax.jit
     def update(p, m, g, scale):

@@ -29,6 +29,13 @@ computes what the configuration states:
 ``quant="fp8"`` is the control: the same computation with both operands
 of every matmul rounded to float8 e4m3 (per-tensor scale), the next
 precision below the bfloat16 the configurations serve in.
+
+A configuration file names this module (``"reference": "decoder"``), and
+the harness reaches it only through the cell: besides the forward, loss
+and gradient it gives the weight rule (``leaf_rule``), the file keys the
+program takes beyond the common ones (``PROGRAM_KEYS``) and the per-token
+counts the counters compose (``matmul_flops_per_token``, ``attn_layers``,
+``kv_bytes_per_token``).
 """
 from __future__ import annotations
 
@@ -37,9 +44,22 @@ import math
 import jax
 import jax.numpy as jnp
 
+from bench.common import BenchError
+
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0           # largest finite float8_e4m3fn
 Q_BLOCK = 1024           # query rows per attention block
+
+# the file's model keys -> the program's ArchConfig fields, beyond the
+# keys every architecture has
+PROGRAM_KEYS = {
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "d_ff",
+    "head_dim": "head_dim",
+    "rope_theta": "rope_theta",
+    "qkv_bias": "qkv_bias",
+}
 
 
 def dims_of(conf: dict) -> dict:
@@ -51,6 +71,50 @@ def dims_of(conf: dict) -> dict:
             "V": m["vocab_size"], "theta": float(m["rope_theta"]),
             "eps": float(m["norm_eps"]), "mlp": m["mlp"],
             "tied": bool(m["tie_word_embeddings"])}
+
+
+def leaf_rule(path: tuple, shape: tuple, d: dict) -> tuple[float, float]:
+    """(mean, standard deviation) of a parameter leaf's random values, by
+    its whole path.  Matrices use 1/sqrt(fan-in), so activations keep unit
+    scale through the stack; norm offsets and biases are small but
+    nonzero, so their paths are exercised and checked."""
+    D = d["D"]
+    top = {("embed",): 0.02, ("lm_head",): D ** -0.5, ("final_norm",): 0.1}
+    block = {("attn", "wq"): D ** -0.5, ("attn", "wk"): D ** -0.5,
+             ("attn", "wv"): D ** -0.5,
+             ("attn", "wo"): (d["H"] * d["hd"]) ** -0.5,
+             ("mlp", "wg"): D ** -0.5, ("mlp", "wu"): D ** -0.5,
+             ("mlp", "wd"): d["F"] ** -0.5,
+             ("attn", "bq"): 0.1, ("attn", "bk"): 0.1, ("attn", "bv"): 0.1,
+             ("ln1",): 0.1, ("ln2",): 0.1}
+    if path in top:
+        return 0.0, top[path]
+    if len(path) > 2 and path[0] == "blocks" and path[2:] in block:
+        return 0.0, block[path[2:]]
+    raise BenchError(f"no weight rule for parameter leaf {'/'.join(path)}")
+
+
+# ---------------------------------------------------------------------------
+# per-token counts (bench/counters.py composes them)
+
+
+def matmul_flops_per_token(d: dict) -> float:
+    """Forward matmul operations of one token through the whole model:
+    the q/k/v/o projections, the MLP and the LM head."""
+    D, H, K, hd, F = d["D"], d["H"], d["K"], d["hd"], d["F"]
+    attn = D * H * hd + 2 * D * K * hd + H * hd * D
+    mlp = (3 if d["mlp"] == "swiglu" else 2) * D * F
+    return 2.0 * (d["L"] * (attn + mlp) + D * d["V"])
+
+
+def attn_layers(d: dict) -> int:
+    """Layers that attend over the KV cache: every layer."""
+    return d["L"]
+
+
+def kv_bytes_per_token(d: dict, itemsize: int) -> int:
+    """Bytes of K and V one token keeps over every attention layer."""
+    return 2 * attn_layers(d) * d["K"] * d["hd"] * itemsize
 
 
 def _fp8(x):

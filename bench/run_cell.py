@@ -27,7 +27,6 @@ import time
 T_PROC0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from dataclasses import dataclass  # noqa: E402
@@ -67,12 +66,8 @@ class ReadCtx:
 
 
 def load_reader(name: str):
-    path = common.BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return common.load_module(common.BENCH / "metrics" / f"{name}.py",
+                              "bench_metric_").read
 
 
 def per_layer(cell, run, dims, peaks, trace_dir) -> tuple[dict, dict, dict]:
@@ -139,10 +134,9 @@ def main(argv=None) -> int:
 
 def run(cell, args, device, clock=None) -> int:
     from bench import serve_driver, train_driver
-    from bench.reference import decoder
     clock = clock or common.Clock()
-    cfg = common.arch_config(cell.config)
-    dims = decoder.dims_of(cell.config)
+    cfg = common.arch_config(cell.config, cell.reference)
+    dims = cell.dims
     trace_dir = None
     if args.trace:
         # one trace at a time, kept until the next traced run

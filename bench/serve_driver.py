@@ -244,9 +244,9 @@ def drive(cell, cfg, seed: int, seconds: float, trace_dir, clock,
     the reference.  Returns a dict the entry point turns into the result
     line."""
     import jax
-    from .reference import check, decoder
-    conf, mix = cell.config, cell.mix
-    params = common.make_params(cfg, seed)
+    from .reference import check
+    conf, mix, ref, d = cell.config, cell.mix, cell.reference, cell.dims
+    params = common.make_params(cfg, seed, ref, d)
     engine, eng = build_engine(cfg, conf, mix, params, seed)
     if fault is not None:
         fault(engine)
@@ -286,8 +286,7 @@ def drive(cell, cfg, seed: int, seconds: float, trace_dir, clock,
     picked = sample_for_check(finished, rng, chk["tokens"])
     by_idx = {r.idx: r for r in reqs}
     samples = [(by_idx[r.idx].prompt, r.tokens) for r in picked]
-    d = decoder.dims_of(conf)
-    res = check.serve_gaps(params, samples, d, mix["sampling"],
+    res = check.serve_gaps(ref, params, samples, d, mix["sampling"],
                            max_out=mix["output"]["max"], seed=seed,
                            control=control)
     # the limits file names the numbers compared; every gap is logged

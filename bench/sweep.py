@@ -35,8 +35,8 @@ def main():
     device = common.check_device(cell.chips)
     from bench import serve_driver, traffic
     clock = common.Clock()
-    cfg = common.arch_config(cell.config)
-    params = common.make_params(cfg, args.seed)
+    cfg = common.arch_config(cell.config, cell.reference)
+    params = common.make_params(cfg, args.seed, cell.reference, cell.dims)
     engine, _ = serve_driver.build_engine(cfg, cell.config, cell.mix, params,
                                           args.seed)
     for rate in [float(r) for r in args.rates.split(",")]:

@@ -29,7 +29,8 @@ def _f32(conf_name):
     conf["kv_pool"]["bytes_per_token"] = (
         m["num_hidden_layers"] * 2 * m["num_key_value_heads"]
         * m["head_dim"] * 4)
-    return conf, common.arch_config(conf), decoder.dims_of(conf)
+    d = decoder.dims_of(conf)
+    return conf, common.arch_config(conf, decoder), d
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -37,7 +38,7 @@ def test_paged_prefill_then_decode_matches_reference(name):
     from repro.models import get_model
     conf, cfg, d = _f32(name)
     model = get_model(cfg)
-    params = common.make_params(cfg, 3)
+    params = common.make_params(cfg, 3, decoder, d)
     bs, P, C = 16, 8, 16
     cache = model.make_paged_cache(1 + P, bs, 1)
     table = jnp.arange(1, P + 1, dtype=jnp.int32)[None]
@@ -75,7 +76,7 @@ def test_trainer_loss_and_gradients_match_reference(name):
     from repro.models import get_model
     conf, cfg, d = _f32(name)
     model = get_model(cfg)
-    params = common.make_params(cfg, 5)
+    params = common.make_params(cfg, 5, decoder, d)
     toks = jnp.asarray(np.random.default_rng(1).integers(
         0, cfg.vocab, (2, 48)), jnp.int32)
     (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(
@@ -91,7 +92,7 @@ def test_trainer_loss_and_gradients_match_reference(name):
 
 def test_fp8_control_departs_from_the_reference():
     conf, cfg, d = _f32("qwen1.5-0.5b")
-    params = common.make_params(cfg, 9)
+    params = common.make_params(cfg, 9, decoder, d)
     toks = jnp.asarray(np.random.default_rng(2).integers(1, cfg.vocab, 64),
                        jnp.int32)
     rows = jnp.arange(64)

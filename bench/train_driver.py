@@ -66,19 +66,22 @@ def build(cfg, conf, chips: int):
 
 def drive(cell, cfg, seed: int, seconds: float, trace_dir, clock,
           t_proc0: float, fault=None, control=False):
+    """Set up, run the window, read memory, free the trainer, check the
+    first steps against the reference.  ``fault(trainer, mesh)`` returns
+    a broken step to run in place of the trainer's (calibration only)."""
     import contextlib
 
     import jax
     import jax.numpy as jnp
-    from .reference import check, decoder
-    conf, mix = cell.config, cell.mix
+    from .reference import check
+    conf, mix, ref, d = cell.config, cell.mix, cell.reference, cell.dims
     chips = cell.chips
     B = mix["batch_per_chip"] * chips
     S = mix["seq"]
     n_check = mix["check_steps"]
     trainer, mesh = build(cfg, conf, chips)
     if fault is not None:
-        faulty = fault(trainer._make_step())
+        faulty = fault(trainer, mesh)
         trainer._make_step = lambda: faulty
     ctx = jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
     sharding = None
@@ -89,7 +92,7 @@ def drive(cell, cfg, seed: int, seconds: float, trace_dir, clock,
     first = [next(batches) for _ in range(n_check)]
     wd = conf["trainer"]["weight_decay"]
     with ctx:
-        p0 = common.make_params(cfg, seed, sharding)
+        p0 = common.make_params(cfg, seed, ref, d, sharding)
         o0 = trainer.optimizer.init(p0)
         # step 1, then the first gradient from the momentum it left
         p1, o1 = trainer.fit(iter([{"tokens": first[0]}]), state=(p0, o0))
@@ -100,7 +103,7 @@ def drive(cell, cfg, seed: int, seconds: float, trace_dir, clock,
         p, o = trainer.fit(iter([{"tokens": b} for b in first[1:]]),
                            state=(p1, o1), start_step=1)
         del p1, o1
-        p0 = common.make_params(cfg, seed, sharding)
+        p0 = common.make_params(cfg, seed, ref, d, sharding)
         change_prog = check.leaf_norms(
             jax.tree.map(lambda a, b: a.astype(jnp.float32)
                          - b.astype(jnp.float32), p, p0))
@@ -129,16 +132,20 @@ def drive(cell, cfg, seed: int, seconds: float, trace_dir, clock,
     del p, o, trainer
     gc.collect()
 
-    # the reference follows the first steps from the same weights
-    d = decoder.dims_of(conf)
-    p0 = common.make_params(cfg, seed)
-    ref = check.reference_steps(p0, first, d, conf["trainer"])
-    ctl = (check.reference_steps(p0, first, d, conf["trainer"], quant="fp8")
+    # the reference follows the first steps from the same weights, its
+    # rows split over the cell's chips
+    devices = jax.devices()[:chips]
+    p0 = common.make_params(cfg, seed, ref, d, sharding)
+    want = check.reference_steps(ref, p0, first, d, conf["trainer"],
+                                 devices=devices)
+    ctl = (check.reference_steps(ref, p0, first, d, conf["trainer"],
+                                 quant="fp8", devices=devices)
            if control else None)
     del p0
-    losses_ref, g_ref, change_ref = ref
+    losses_ref, g_ref, change_ref = want
     moving = check.moving_leaves(g_ref)
-    gaps = check.train_gaps((losses_prog, g_prog, change_prog), ref, moving)
+    gaps = check.train_gaps((losses_prog, g_prog, change_prog), want,
+                            moving)
     (loss_gap, _), (grad_gap, grad_at), (change_gap, change_at) = gaps
     lim = cell.limits
     checks = {"loss_rel_gap": {"value": loss_gap, "limit": lim["loss_rel_gap"]},
@@ -152,7 +159,8 @@ def drive(cell, cfg, seed: int, seconds: float, trace_dir, clock,
                              "change_worst_leaf": change_at,
                              "left_out": sorted(set(g_ref) - moving)})
     ctl_read = None if ctl is None else {
-        k: v for k, (v, _) in zip(checks, check.train_gaps(ctl, ref, moving))}
+        k: v for k, (v, _) in zip(checks, check.train_gaps(ctl, want,
+                                                           moving))}
     return {"metrics": metrics, "attempted": steps_in, "failed": failed,
             "memory_peak_bytes": mem, "checks": checks,
             "correct": common.within({k: c["value"]
