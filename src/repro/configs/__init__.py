@@ -7,7 +7,8 @@ from __future__ import annotations
 
 from repro.models.common import ArchConfig
 
-from . import (dbrx_132b, gemma2_2b, granite_20b, internvl2_76b,
+from . import (dbrx_132b, gemma2_2b, granite_4_0_h_small, granite_20b,
+               internvl2_76b,
                jamba_1_5_large_398b, llama4_scout_17b_a16e, mamba2_130m,
                qwen1_5_0_5b, starcoder2_15b, whisper_base)
 
@@ -15,7 +16,7 @@ _MODULES = {
     m.ARCH_ID: m
     for m in (dbrx_132b, internvl2_76b, qwen1_5_0_5b, gemma2_2b,
               jamba_1_5_large_398b, whisper_base, llama4_scout_17b_a16e,
-              starcoder2_15b, mamba2_130m, granite_20b)
+              starcoder2_15b, mamba2_130m, granite_20b, granite_4_0_h_small)
 }
 
 ARCH_IDS = list(_MODULES)

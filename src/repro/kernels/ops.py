@@ -16,6 +16,7 @@ from __future__ import annotations
 import jax
 
 from . import registry
+from .expert_gmm import expert_gmm as _gmm
 from .flash_attention import flash_attention as _flash
 from .fused_update import sgd_momentum as _sgd
 from .paged_attention import paged_attention as _paged
@@ -27,7 +28,11 @@ _flash_jit = jax.jit(_flash, static_argnames=(
     "return_carry", "block_q", "block_k", "interpret"))
 
 _paged_jit = jax.jit(_paged, static_argnames=(
-    "window", "softcap", "pages_per_step", "head_tile", "interpret"))
+    "window", "softcap", "scale", "pages_per_step", "head_tile",
+    "interpret"))
+
+_gmm_jit = jax.jit(_gmm, static_argnames=("block_rows", "block_ff",
+                                           "interpret"))
 
 _rmsnorm_jit = jax.jit(_rmsnorm, static_argnames=("eps", "block_rows",
                                                   "interpret"))
@@ -54,7 +59,8 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, layer, *,
                     k_scale=None, v_scale=None, window=None, softcap=None,
-                    pages_per_step=None, head_tile=None, interpret=None):
+                    scale=None, pages_per_step=None, head_tile=None,
+                    interpret=None):
     p = registry.resolve(
         "paged_attention",
         {"pages_per_step": pages_per_step, "head_tile": head_tile},
@@ -63,7 +69,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, layer, *,
             k_scale=k_scale))
     return _paged_jit(q, k_pages, v_pages, block_tables, lengths, layer,
                       k_scale=k_scale, v_scale=v_scale, window=window,
-                      softcap=softcap, interpret=interpret, **p)
+                      softcap=softcap, scale=scale, interpret=interpret, **p)
+
+
+def expert_gmm(x, wg, wu, wd, tile_group, n_tiles, *, block_rows,
+               block_ff=None, interpret=None):
+    p = registry.resolve("expert_gmm", {"block_ff": block_ff},
+                         registry.get("expert_gmm").bucket_of(
+                             x, wg, wu, wd, tile_group, n_tiles,
+                             block_rows=block_rows))
+    return _gmm_jit(x, wg, wu, wd, tile_group, n_tiles,
+                    block_rows=block_rows, interpret=interpret, **p)
 
 
 def rmsnorm(x, weight, eps=1e-6, block_rows=None, interpret=None):

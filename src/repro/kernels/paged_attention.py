@@ -170,7 +170,8 @@ def _paged_kernel(layer_ref, tables_ref, lens_ref, q_ref, *refs, scale,
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, layer, *,
                     k_scale=None, v_scale=None, window=None, softcap=None,
-                    pages_per_step=1, head_tile=16, interpret=None):
+                    scale=None, pages_per_step=1, head_tile=16,
+                    interpret=None):
     """Single-token attention through one layer of a stacked paged pool.
 
     q: (B, H, hd) — the current token's query rows;
@@ -184,6 +185,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, layer, *,
     layer: int32 scalar, which of the L layers to read (0 when L == 1);
     k_scale/v_scale: (L, num_blocks, block_size, K) f32 per-row scales
     when the pools are quantized (both or neither);
+    scale: the score scale, a static float (None: hd^-0.5);
     pages_per_step / head_tile: grid tunables (see module docstring) —
     pure schedule knobs, the output is bitwise independent of them up to
     f32 summation order; ``head_tile`` goes through
@@ -214,7 +216,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, layer, *,
 
     qg = q.reshape(B, K, G, hd)
     kernel = functools.partial(
-        _paged_kernel, scale=1.0 / math.sqrt(hd), block_size=bs,
+        _paged_kernel, scale=1.0 / math.sqrt(hd) if scale is None else scale,
+        block_size=bs,
         head_dim=hd, n_steps=n_steps, pps=pps, quant=quant, window=window,
         softcap=softcap)
 

@@ -33,7 +33,7 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None, softcap=None,
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, layer,
                         *, k_scale=None, v_scale=None, window=None,
-                        softcap=None):
+                        softcap=None, scale=None):
     """q: (B, H, hd); pools: (L, NB, bs, K*hd) stacked lane-dense;
     block_tables: (B, P) int32; lengths: (B,) live tokens incl. the
     current one; layer: which of the L layers to read.  Gathers the
@@ -44,7 +44,8 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, layer,
 
     ``k_scale``/``v_scale``: (L, NB, bs, K) f32 per-(token, kv-head)
     scales for quantized pools (DESIGN.md §13) — rows dequantize as
-    ``row.astype(f32) * scale`` before attention."""
+    ``row.astype(f32) * scale`` before attention.  ``scale``: the score
+    scale (None: hd^-0.5)."""
     B, H, hd = q.shape
     bs = k_pages.shape[2]
     K = k_pages.shape[3] // hd
@@ -60,7 +61,8 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, layer,
         k, v = kv_dequantize(k, ks), kv_dequantize(v, vs)
     qg = q.reshape(B, K, G, hd)
     s = jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
-                   k.astype(jnp.float32)) / np.sqrt(hd)
+                   k.astype(jnp.float32))
+    s = s / np.sqrt(hd) if scale is None else s * scale
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
     kpos = jnp.arange(P * bs)
@@ -74,6 +76,26 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, layer,
     p = p / jnp.where(l == 0.0, 1.0, l)                   # empty lane -> 0
     out = jnp.einsum("bkgs,bskh->bkgh", p, v.astype(jnp.float32))
     return out.reshape(B, H, hd).astype(q.dtype)
+
+
+def expert_gmm_ref(x, wg, wu, wd, tile_group, n_tiles, *, block_rows):
+    """Oracle for ``kernels.expert_gmm.expert_gmm``: each row of the
+    tiles before ``n_tiles`` through its tile's expert's SwiGLU,
+    ``silu(x Wg) * (x Wu) Wd`` with f32 accumulation; rows of later tiles
+    are zeros (the kernel leaves them unwritten).  Every expert is applied
+    to every row and masked, which is fine at oracle sizes and is the CPU
+    path the expert layer takes."""
+    M = x.shape[0]
+    row_e = jnp.repeat(tile_group, block_rows, total_repeat_length=M)
+    live = jnp.arange(M) < n_tiles * block_rows
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(wg.shape[0]):
+        g = jnp.dot(x, wg[e], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu[e], preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = jnp.dot(h, wd[e], preferred_element_type=jnp.float32)
+        out = jnp.where(((row_e == e) & live)[:, None], y, out)
+    return out.astype(x.dtype)
 
 
 def rmsnorm_ref(x, weight, eps=1e-6):

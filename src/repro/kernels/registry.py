@@ -24,7 +24,7 @@ gets tuned parameters with no signature change, and a hand-passed
 >>> pow2_bucket(300)
 512
 >>> sorted(ops())[:3]
-['flash_attention', 'paged_attention', 'rmsnorm']
+['expert_gmm', 'flash_attention', 'paged_attention']
 >>> resolve("rmsnorm", {"block_rows": None}, "rows=512,d=256,f32")
 {'block_rows': 256}
 >>> resolve("rmsnorm", {"block_rows": 64}, "rows=512,d=256,f32")
@@ -123,6 +123,7 @@ def _rand(key, shape, dtype=jnp.float32):
 
 def _register_all():
     from . import ref
+    from .expert_gmm import expert_gmm
     from .flash_attention import flash_attention
     from .fused_update import sgd_momentum
     from .paged_attention import paged_attention
@@ -190,6 +191,34 @@ def _register_all():
             ("decode_B4", paged_case(4, 8, 40, 16, 8, 2, 64)),
             ("decode_B4_int8", paged_case(4, 8, 40, 16, 8, 2, 64,
                                           kv_dtype=jnp.int8)))))
+
+    def gmm_bucket(x, wg, wu, wd, tile_group, n_tiles, **kw):
+        M, D = x.shape
+        E, _, F = wg.shape
+        return (f"M={pow2_bucket(M)},tm={kw.get('block_rows')},D={D},F={F},"
+                f"E={E},{_dt(x.dtype)}")
+
+    def gmm_case(E, D, F, tm, sizes):
+        def make():
+            import numpy as np
+            padded = [-(-n // tm) * tm for n in sizes]
+            M = sum(padded) + tm
+            tg = np.repeat(np.arange(E), [p // tm for p in padded])
+            tg = np.concatenate([tg, np.full(M // tm - len(tg), E - 1)])
+            return ((_rand(0, (M, D)), _rand(1, (E, D, F)) * D ** -0.5,
+                     _rand(2, (E, D, F)) * D ** -0.5,
+                     _rand(3, (E, F, D)) * F ** -0.5,
+                     jnp.asarray(tg, jnp.int32),
+                     jnp.int32(sum(padded) // tm)), {"block_rows": tm})
+        return make
+
+    register(OpSpec(
+        name="expert_gmm", impl=expert_gmm,
+        reference=ref.expert_gmm_ref,
+        tunables={"block_ff": (128, 256, 512)},
+        defaults={"block_ff": 256},
+        bucket_of=gmm_bucket,
+        bench_cases=(("E4_rows", gmm_case(4, 256, 512, 16, (9, 0, 30, 3))),)))
 
     def rmsnorm_bucket(x, weight, **kw):
         rows = 1

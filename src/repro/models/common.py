@@ -41,13 +41,20 @@ class ArchConfig:
 
     head_dim: int | None = None        # default d_model // n_heads
     # --- MoE
-    n_experts: int = 0
+    n_experts: int = 0                 # experts the router scores
     top_k: int = 0
     capacity_factor: float = 1.25
     shared_expert: bool = False        # llama4-style always-on expert
+    d_ff_shared: int = 0               # shared expert's width (0: d_ff)
+    # the share of the experts this chip holds under expert parallelism:
+    # experts [expert_offset, expert_offset + experts_held); 0 = all
+    experts_held: int = 0
+    expert_offset: int = 0
     # --- attention details
     qkv_bias: bool = False
+    position_embedding: Literal["rope", "nope"] = "rope"
     rope_theta: float = 10_000.0
+    attention_multiplier: float | None = None   # score scale; None: hd^-0.5
     attn_softcap: float | None = None  # gemma2 logit soft-capping
     final_softcap: float | None = None
     # --- SSM (mamba2 / SSD)
@@ -56,6 +63,7 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 128
     conv_width: int = 4
+    ssm_gated_norm: bool = False       # Mamba-2's w * rmsnorm(y * silu(z))
     # --- enc-dec / multimodal stubs
     encoder_layers: int = 0            # whisper encoder depth
     frontend_tokens: int = 0           # stub patch/frame embeddings length
@@ -65,6 +73,11 @@ class ArchConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     sandwich_norm: bool = False        # gemma2 pre+post block norms
+    # Granite's scalar multipliers (1.0: absent): x0 = embed * e, each
+    # sublayer's output * r before the residual add, logits / s
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # activation rematerialization at super-block granularity (the pjit-path
     # analogue of the paper's memory planning — DESIGN.md §2)
     remat: bool = True
@@ -82,6 +95,15 @@ class ArchConfig:
         return self.n_layers // len(self.pattern)
 
     @property
+    def d_shared(self) -> int:
+        return self.d_ff_shared or self.d_ff
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -97,34 +119,39 @@ class ArchConfig:
     def param_count(self, active_only: bool = False) -> int:
         D, F, V = self.d_model, self.d_ff, self.vocab
         H, K, hd = self.n_heads, self.n_kv_heads, self.hd
-        total = V * D  # embed
+        total = V * D + D  # embed, final norm
         if not self.tie_embeddings:
             total += D * V
         for spec in self.pattern:
             n = self.n_super
             if spec.kind == "attn":
                 attn = D * H * hd + 2 * D * K * hd + H * hd * D
-                total += n * (attn + 2 * D)  # + norms
+                total += n * (attn + D)      # + the mixer's norm
                 if spec.cross_attn:
                     total += n * (attn + D)
             else:  # mamba2 (B/C shared across heads: n_groups=1)
                 di, N, Hs = self.d_inner, self.ssm_state, self.ssm_heads
                 ssm = (D * (2 * di + 2 * N + Hs)   # in_proj (z,x,B,C,dt)
-                       + self.conv_width * (di + 2 * N)
-                       + di * D + 3 * Hs)
+                       + (self.conv_width + 1) * (di + 2 * N)
+                       + di * D + 3 * Hs
+                       + (di if self.ssm_gated_norm else 0))
                 total += n * (ssm + D)
             if spec.mlp == "moe":
-                e_all = self.n_experts
-                e_act = self.top_k + (1 if self.shared_expert else 0)
+                # the router scores every expert; this chip holds
+                # n_held of them, of which a token reaches top_k * n_held
+                # / n_experts in expectation; the shared expert once
+                held = self.n_held
                 per_expert = 3 * D * F
-                total += n * (D * e_all + 2 * D)
-                total += n * per_expert * (e_act if active_only else e_all)
+                total += n * (D * self.n_experts + D)   # router, norm
+                total += round(n * per_expert * (
+                    self.top_k * held / self.n_experts if active_only
+                    else held))
                 if self.shared_expert:
-                    total += 0 if active_only else 0  # counted in e_all? no:
+                    total += n * 3 * D * self.d_shared
             elif spec.mlp in ("swiglu", "geglu"):
-                total += n * (3 * D * F + 2 * D)
+                total += n * (3 * D * F + D)
             elif spec.mlp == "gelu":
-                total += n * (2 * D * F + 2 * D)
+                total += n * (2 * D * F + D)
         if self.encoder_layers:
             attn = D * H * hd + 2 * D * K * hd + H * hd * D
             mlp = 2 * D * F
@@ -169,6 +196,9 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
         head_dim=64,
         n_experts=4 if cfg.n_experts else 0,
         top_k=min(cfg.top_k, 2) if cfg.top_k else 0,
+        d_ff_shared=384 if cfg.d_ff_shared else 0,
+        experts_held=0,
+        expert_offset=0,
         # drop-free capacity so prefill/decode routing agree exactly in tests
         capacity_factor=8.0,
         ssm_state=32 if cfg.ssm_state else 0,

@@ -99,17 +99,24 @@ def ring_selected(Sq: int) -> bool:
     return n > 1 and Sq % n == 0
 
 
+def _scale(hd, scale):
+    """The score scale: ``scale`` where the configuration gives one
+    (``ArchConfig.attention_multiplier``), else hd^-0.5."""
+    return 1.0 / np.sqrt(hd) if scale is None else scale
+
+
 def gqa_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                  q_offset=0):
+                  q_offset=0, scale=None):
     """Grouped-query attention.
 
     q: (B, Sq, H, hd);  k, v: (B, Sk, K, hd) with H % K == 0.
     ``q_offset``: absolute position of q[0] (decode: cache length).
     ``window``: sliding window in tokens (None = full).
+    ``scale``: score scale (None: hd^-0.5).
     """
     B, Sq, H, hd = q.shape
 
-    if _USE_PALLAS and Sq > 1:
+    if _USE_PALLAS and Sq > 1 and scale is None:
         from repro.kernels.ops import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, q_offset=q_offset)
@@ -119,7 +126,8 @@ def gqa_attention(q, k, v, *, causal=True, window=None, softcap=None,
     if Sq > qc and FLAGS.attn_chunk_parallel:
         return _attention_chunk_parallel(q, k, v, causal=causal,
                                          window=window, softcap=softcap,
-                                         q_offset=q_offset, qc=qc)
+                                         q_offset=q_offset, qc=qc,
+                                         scale=scale)
     if Sq > qc:
         Sk = k.shape[1]
         nc = (Sq + qc - 1) // qc
@@ -137,14 +145,14 @@ def gqa_attention(q, k, v, *, causal=True, window=None, softcap=None,
                 kc, vc = k[:, k0:kend], v[:, k0:kend]
             outs.append(_attention_dense(
                 q[:, lo:hi], kc, vc, causal=causal, window=window,
-                softcap=softcap, q_offset=q_offset + lo - k0))
+                softcap=softcap, q_offset=q_offset + lo - k0, scale=scale))
         return jnp.concatenate(outs, axis=1)
     return _attention_dense(q, k, v, causal=causal, window=window,
-                            softcap=softcap, q_offset=q_offset)
+                            softcap=softcap, q_offset=q_offset, scale=scale)
 
 
 def _attention_chunk_parallel(q, k, v, *, causal, window, softcap,
-                              q_offset, qc):
+                              q_offset, qc, scale=None):
     """Blockwise attention with the q-chunk dim sharded over "model".
 
     All chunks compute in parallel across model ranks (k/v replicated);
@@ -156,7 +164,7 @@ def _attention_chunk_parallel(q, k, v, *, causal, window, softcap,
     B, Sq, H, hd = q.shape
     Sk, K = k.shape[1], k.shape[2]
     G = H // K
-    scale = 1.0 / np.sqrt(hd)
+    scale = _scale(hd, scale)
     pad = (-Sq) % qc
     if pad:
         q = jnp.pad(q, [(0, 0), (0, pad), (0, 0), (0, 0)])
@@ -188,11 +196,12 @@ def _attention_chunk_parallel(q, k, v, *, causal, window, softcap,
     return out.astype(q.dtype)
 
 
-def _attention_dense(q, k, v, *, causal, window, softcap, q_offset):
+def _attention_dense(q, k, v, *, causal, window, softcap, q_offset,
+                     scale=None):
     B, Sq, H, hd = q.shape
     Sk, K = k.shape[1], k.shape[2]
     G = H // K
-    scale = 1.0 / np.sqrt(hd)
+    scale = _scale(hd, scale)
     qg = q.reshape(B, Sq, K, G, hd)
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
@@ -225,7 +234,8 @@ def _attention_dense(q, k, v, *, causal, window, softcap, q_offset):
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, *, softcap=None):
+def decode_attention(q, k_cache, v_cache, cache_len, *, softcap=None,
+                     scale=None):
     """One-token attention against a (possibly ring-buffered) KV cache.
 
     q: (B, 1, H, hd); caches: (B, S, K, hd); cache_len: filled length —
@@ -237,7 +247,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, softcap=None):
     B, _, H, hd = q.shape
     S, K = k_cache.shape[1], k_cache.shape[2]
     G = H // K
-    scale = 1.0 / np.sqrt(hd)
+    scale = _scale(hd, scale)
     qg = q.reshape(B, K, G, hd)
     scores = jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
                         k_cache.astype(jnp.float32)) * scale
@@ -253,7 +263,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, softcap=None):
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
                            layer, *, k_scale=None, v_scale=None, window=None,
-                           softcap=None):
+                           softcap=None, scale=None):
     """One-token attention through layer ``layer`` of the stacked paged
     pool (DESIGN.md §9).
 
@@ -264,21 +274,22 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     grid in Python).
     ``k_scale``/``v_scale``: (L, NB, bs, K) f32 per-row scales when the
     pools are quantized (DESIGN.md §13); both paths fuse the dequant into
-    attention — no full-precision cache copy.
+    attention — no full-precision cache copy.  ``scale``: the score scale
+    (None: hd^-0.5).
     """
     if jax.default_backend() == "tpu":
         from repro.kernels.ops import paged_attention
         return paged_attention(q, k_pages, v_pages, block_tables, lengths,
                                layer, k_scale=k_scale, v_scale=v_scale,
-                               window=window, softcap=softcap)
+                               window=window, softcap=softcap, scale=scale)
     from repro.kernels.ref import paged_attention_ref
     return paged_attention_ref(q, k_pages, v_pages, block_tables, lengths,
                                layer, k_scale=k_scale, v_scale=v_scale,
-                               window=window, softcap=softcap)
+                               window=window, softcap=softcap, scale=scale)
 
 
 def paged_context_attention(q, k_ctx, v_ctx, *, q_offset, kv_len,
-                            window=None, softcap=None):
+                            window=None, softcap=None, scale=None):
     """Chunked-prefill attention against gathered paged context.
 
     q: (B, C, H, hd) — the prompt chunk's queries; k_ctx/v_ctx:
@@ -291,7 +302,7 @@ def paged_context_attention(q, k_ctx, v_ctx, *, q_offset, kv_len,
     B, C, H, hd = q.shape
     Sk, K = k_ctx.shape[1], k_ctx.shape[2]
     G = H // K
-    scale = 1.0 / np.sqrt(hd)
+    scale = _scale(hd, scale)
     qg = q.reshape(B, C, K, G, hd)
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
                         k_ctx.astype(jnp.float32)) * scale
@@ -340,14 +351,16 @@ def attn_project_qkv(p, x, cfg):
     return q, k, v
 
 
-def attn_block(p, x, cfg, spec, positions=None, rope=True):
-    """Full-sequence attention block (training / prefill).
+def attn_block(p, x, cfg, spec, positions=None):
+    """Full-sequence attention block (training / prefill); rotary
+    positions unless the configuration has none (``position_embedding``
+    "nope").
 
     Returns (out, (k, v)) — the kv tensors become the prefill cache.
     """
     B, S, D = x.shape
     q, k, v = attn_project_qkv(p, x, cfg)
-    if rope:
+    if cfg.position_embedding == "rope":
         pos = positions if positions is not None else jnp.arange(S)
         cos, sin = rope_freqs(pos, cfg.hd, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
@@ -356,6 +369,9 @@ def attn_block(p, x, cfg, spec, positions=None, rope=True):
     if causal and S > 1 and ring_selected(S):
         # sequence-sharded ring schedule (DESIGN.md §8): S stays sharded
         # over "model" end to end; per-device attention state is O(S·S/P)
+        if cfg.attention_multiplier is not None:
+            raise ValueError("the ring schedule scales scores by hd^-0.5; "
+                             "it takes no attention_multiplier")
         from repro.dist.ring import ring_attention
         out = ring_attention(q, k, v, causal=True, window=spec.window,
                              softcap=cfg.attn_softcap,
@@ -363,7 +379,8 @@ def attn_block(p, x, cfg, spec, positions=None, rope=True):
         out = ann(out, BATCH, "model", None, None)
     else:
         out = gqa_attention(q, k, v, causal=causal,
-                            window=spec.window, softcap=cfg.attn_softcap)
+                            window=spec.window, softcap=cfg.attn_softcap,
+                            scale=cfg.attention_multiplier)
         out = ann(out, BATCH, None, "model", None)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     # sequence-parallel output: the heads-contraction all-reduce becomes a
@@ -379,13 +396,14 @@ def attn_block_decode(p, x, cache_k, cache_v, pos, cfg, spec):
     For windowed layers the cache is a ring buffer of size ``window``."""
     q, k, v = attn_project_qkv(p, x, cfg)
     pos = jnp.asarray(pos)
-    if pos.ndim == 0:
-        cos, sin = rope_freqs(pos[None], cfg.hd, cfg.rope_theta)
-        cos, sin = cos[None], sin[None]          # (1, 1, hd//2), broadcast B
-    else:
-        cos, sin = rope_freqs(pos[:, None], cfg.hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.position_embedding == "rope":
+        if pos.ndim == 0:
+            cos, sin = rope_freqs(pos[None], cfg.hd, cfg.rope_theta)
+            cos, sin = cos[None], sin[None]      # (1, 1, hd//2), broadcast B
+        else:
+            cos, sin = rope_freqs(pos[:, None], cfg.hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     B, S, K, hd = cache_k.shape
     slot = pos % S  # ring for windowed caches; identity else
     if pos.ndim == 0:
@@ -405,7 +423,8 @@ def attn_block_decode(p, x, cache_k, cache_v, pos, cfg, spec):
     # for windowed layers), not by a position mask — ring slots are not in
     # position order.
     out = decode_attention(q, cache_k, cache_v, cache_len,
-                           softcap=cfg.attn_softcap)
+                           softcap=cfg.attn_softcap,
+                           scale=cfg.attention_multiplier)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return out, cache_k, cache_v
 
@@ -461,9 +480,10 @@ def attn_block_decode_paged(p, x, pools, layer, block_tables, pos, cfg,
     lanes must carry sink tables (pos 0, table 0) so their writes land in
     the sink block."""
     q, k, v = attn_project_qkv(p, x, cfg)
-    cos, sin = rope_freqs(pos[:, None], cfg.hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.position_embedding == "rope":
+        cos, sin = rope_freqs(pos[:, None], cfg.hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     bs = pools["k"].shape[2]
     B = q.shape[0]
     page = block_tables[jnp.arange(B), pos // bs]        # physical block
@@ -471,7 +491,8 @@ def attn_block_decode_paged(p, x, pools, layer, block_tables, pos, cfg,
     out = paged_decode_attention(
         q[:, 0], pools["k"], pools["v"], block_tables, pos + 1, layer,
         k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
-        window=spec.window, softcap=cfg.attn_softcap)
+        window=spec.window, softcap=cfg.attn_softcap,
+        scale=cfg.attention_multiplier)
     out = jnp.einsum("bshk,hkd->bsd", out[:, None], p["wo"])
     return out, pools
 

@@ -1,19 +1,34 @@
-"""Mixture-of-Experts layer: token-choice top-k routing with static expert
-capacity and GROUPED dispatch (GShard-style).
+"""Mixture-of-Experts layer: token-choice top-k routing, on one of two
+paths, chosen by what the layer can observe (DESIGN.md §16).
 
-TPU adaptation: the scatter/gather dispatch runs *locally* under
-``shard_map`` over the data axes (each data shard slots its own tokens
-into its local (B_loc, E, C, D) buffer — no partitioner involvement, which
-otherwise replicates batched scatters), while the expert FFN einsum runs
-under GSPMD with experts sharded over the "model" axis — the
-group->expert resharding is the all-to-all of expert parallelism.
+* Drop-free grouped path (``moe_grouped``), where the layer holds a share
+  of the experts (``ArchConfig.experts_held``: one chip of an expert-
+  parallel deployment) or runs on one chip.  The router scores every
+  expert in f32 and each token takes its top-k; the rows routed to the
+  held experts are sorted by expert, each expert's rows padded to a
+  whole tile, and one grouped matmul (``kernels/expert_gmm.py`` on a TPU,
+  its jnp oracle elsewhere) computes each row's own expert.  No row is
+  dropped; rows routed to experts this chip does not hold contribute
+  nothing here (that is the absent chips' part), and nothing stands in
+  for them or for their exchange.
 
-Capacity is per group (= batch row): C = ceil(S·k/E · capacity_factor);
-overflow tokens are dropped (contribute zero), exactly like GShard/Switch.
+* Capacity path (``moe_capacity``), under a mesh of several devices:
+  GShard-style static expert capacity with GROUPED dispatch.  The
+  scatter/gather dispatch runs *locally* under ``shard_map`` over the
+  data axes (each data shard slots its own tokens into its local
+  (B_loc, E, C, D) buffer — no partitioner involvement, which otherwise
+  replicates batched scatters), while the expert FFN einsum runs under
+  GSPMD with experts sharded over the "model" axis — the group->expert
+  resharding is the all-to-all of expert parallelism.  Capacity is per
+  group (= batch row): C = ceil(S·k/E · capacity_factor); overflow tokens
+  are dropped (contribute zero), exactly like GShard/Switch.
 
-Aux losses: Switch load-balance + router z-loss.
+Both add the always-on shared expert, once.  Aux losses: Switch
+load-balance + router z-loss.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +36,16 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.annotate import BATCH, ann, _mesh_axes
+
+
+def _router_aux(logits, idx, E):
+    """Switch load-balance and router z-loss over every expert."""
+    probs = jax.nn.softmax(logits, -1)
+    f = jnp.mean(jax.nn.one_hot(idx, E, dtype=jnp.float32),
+                 axis=tuple(range(idx.ndim)))
+    pbar = probs.reshape(-1, E).mean(0)
+    return {"load_balance": E * jnp.sum(f * pbar),
+            "router_z": jnp.mean(jax.nn.logsumexp(logits, -1) ** 2)}
 
 
 def moe_router(p, x, cfg):
@@ -37,13 +62,7 @@ def moe_router(p, x, cfg):
     probs = jax.nn.softmax(logits, -1)
     w, idx = jax.lax.top_k(probs, cfg.top_k)
     w = w / jnp.clip(w.sum(-1, keepdims=True), 1e-9)
-
-    E = cfg.n_experts
-    f = jnp.mean(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=(0, 1, 2))
-    pbar = probs.mean((0, 1))
-    lb = E * jnp.sum(f * pbar)
-    z = jnp.mean(jax.nn.logsumexp(logits, -1) ** 2)
-    return w.astype(x.dtype), idx, {"load_balance": lb, "router_z": z}
+    return w.astype(x.dtype), idx, _router_aux(logits, idx, cfg.n_experts)
 
 
 # ---------------------------------------------------------------------------
@@ -147,8 +166,130 @@ def _combine_local_kloop(y, idx, w, s_idx, keep):
     return out
 
 
-def moe_block(p, x, cfg, mlp_kind="swiglu"):
-    """x: (B, S, D) -> (B, S, D), aux. Grouped dispatch: group == batch row."""
+def moe_block(p, x, cfg, mlp_kind="swiglu", valid_len=None):
+    """x: (B, S, D) -> (B, S, D), aux: the drop-free grouped path where
+    the layer holds a share of the experts or runs on one chip, the
+    capacity path under a mesh of several devices.  ``valid_len``: only
+    the first ``valid_len`` rows of each sequence are real (a chunked
+    prefill's padded tail is routed nowhere on the grouped path)."""
+    if grouped_path(cfg):
+        return moe_grouped(p, x, cfg, valid_len=valid_len)
+    return moe_capacity(p, x, cfg, mlp_kind)
+
+
+def grouped_path(cfg) -> bool:
+    """Whether the expert layer runs drop-free: it holds a share of the
+    experts, or no mesh spreads it over several devices."""
+    if cfg.experts_held:
+        return True
+    _, sizes = _mesh_axes()
+    return all(n == 1 for n in sizes.values())
+
+
+def _shared_expert(p, x):
+    hs = jax.nn.silu(x @ p["shared_wg"]) * (x @ p["shared_wu"])
+    hs = ann(hs, BATCH, None, "model")
+    return hs @ p["shared_wd"]
+
+
+def rows_per_tile(tokens: int, top_k: int, n_experts: int) -> int:
+    """Row tile of the grouped matmul: the expected rows of one expert
+    (tokens x top_k / experts) rounded up to a power of two, 16 to 128 (a
+    tile that an expert's rows fill, and never one past the MXU's
+    height)."""
+    want = -(-tokens * top_k // n_experts)
+    return min(128, max(16, 1 << (want - 1).bit_length()))
+
+
+def grouped_swiglu(x, wg, wu, wd, tile_group, n_tiles, block_rows):
+    """Each row tile of ``x`` through its expert's SwiGLU: the
+    ``expert_gmm`` kernel on a TPU (its backward from the oracle's), the
+    jnp oracle elsewhere (interpret-mode Pallas runs its grid in
+    Python)."""
+    from repro.kernels.ref import expert_gmm_ref
+    ref = functools.partial(expert_gmm_ref, block_rows=block_rows)
+    if jax.default_backend() != "tpu":
+        return ref(x, wg, wu, wd, tile_group, n_tiles)
+    from repro.kernels.ops import expert_gmm
+
+    @jax.custom_vjp
+    def gmm(x, wg, wu, wd):
+        return expert_gmm(x, wg, wu, wd, tile_group, n_tiles,
+                          block_rows=block_rows)
+
+    def fwd(x, wg, wu, wd):
+        return gmm(x, wg, wu, wd), (x, wg, wu, wd)
+
+    def bwd(res, g):
+        _, vjp = jax.vjp(lambda *a: ref(*a, tile_group, n_tiles), *res)
+        return vjp(g)
+
+    gmm.defvjp(fwd, bwd)
+    return gmm(x, wg, wu, wd)
+
+
+def moe_grouped(p, x, cfg, valid_len=None):
+    """Drop-free expert layer over the held experts ``[expert_offset,
+    expert_offset + n_held)``.  x: (B, S, D) -> (B, S, D), aux.
+
+    1. router logits in f32 over all ``n_experts``; 2. each token's top-k;
+    3. gates: softmax over the k chosen logits; 4. each row routed to a
+    held expert through that expert's SwiGLU (one grouped matmul over
+    rows sorted by expert, each expert's rows padded to a whole tile);
+    5. rows routed to other experts add nothing here; 6. the shared
+    expert, for every token, once.  ``aux`` carries, beside the router
+    losses, ``rows`` (rows the held experts received) and ``rows_max``
+    (the busiest held expert's)."""
+    B, S, D = x.shape
+    T, k, E = B * S, cfg.top_k, cfg.n_experts
+    Eh, lo = cfg.n_held, cfg.expert_offset
+    xf = x.reshape(T, D)
+    logits = jnp.dot(xf.astype(jnp.float32),
+                     p["router"].astype(jnp.float32))            # (T, E)
+    top, idx = jax.lax.top_k(logits, k)
+    gates = jax.nn.softmax(top, -1)                              # (T, k)
+    aux = _router_aux(logits, idx, E)
+
+    tm = rows_per_tile(T, k, E)
+    # worst case: every token's choices among the held experts, plus each
+    # expert's padding to a whole tile
+    M = -(-(T * min(k, Eh) + Eh * (tm - 1)) // tm) * tm
+    e = (idx - lo).reshape(T * k)
+    held = (e >= 0) & (e < Eh)
+    if valid_len is not None:
+        real = jnp.arange(S)[None, :] < jnp.asarray(valid_len).reshape(-1, 1)
+        held &= jnp.repeat(jnp.broadcast_to(real, (B, S)).reshape(T), k)
+    e = jnp.where(held, e, Eh)                     # Eh: not held here
+    onehot = jax.nn.one_hot(e, Eh + 1, dtype=jnp.int32)[:, :Eh]
+    counts = onehot.sum(0)                                       # (Eh,)
+    padded = -(-counts // tm) * tm
+    ends = jnp.cumsum(padded)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0),
+                               jnp.minimum(e, Eh - 1)[:, None], 1)[:, 0] - 1
+    pos = jnp.where(held, (ends - padded)[jnp.minimum(e, Eh - 1)] + rank, M)
+    token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    # row r of the sorted buffer holds token row_token[r] (T: a zero pad)
+    row_token = jnp.full((M,), T, jnp.int32).at[pos].set(token, mode="drop")
+    xs = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)])[row_token]
+    tile_group = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(M // tm) * tm, side="right"),
+        Eh - 1).astype(jnp.int32)
+    ys = grouped_swiglu(xs, p["wg"], p["wu"], p["wd"], tile_group,
+                        ends[-1] // tm, tm)                      # (M, D)
+    picked = ys[jnp.minimum(pos, M - 1)].astype(jnp.float32)     # (T*k, D)
+    w = jnp.where(held, gates.reshape(T * k), 0.0)
+    out = jnp.where(held[:, None], picked, 0.0) * w[:, None]
+    out = out.reshape(T, k, D).sum(1).astype(x.dtype).reshape(B, S, D)
+    if cfg.shared_expert:
+        out = out + _shared_expert(p, x)
+    aux["rows"] = counts.sum()
+    aux["rows_max"] = counts.max()
+    return out, aux
+
+
+def moe_capacity(p, x, cfg, mlp_kind="swiglu"):
+    """x: (B, S, D) -> (B, S, D), aux. Grouped dispatch: group == batch
+    row, a static capacity per expert, overflow dropped."""
     B, S, D = x.shape
     E, k = cfg.n_experts, cfg.top_k
     C = max(int(np.ceil(S * k / E * cfg.capacity_factor)), 1)
@@ -203,7 +344,5 @@ def moe_block(p, x, cfg, mlp_kind="swiglu"):
     out = ann(out, BATCH, None, None)
 
     if cfg.shared_expert:
-        hs = jax.nn.silu(x @ p["shared_wg"]) * (x @ p["shared_wu"])
-        hs = ann(hs, BATCH, None, "model")
-        out = out + hs @ p["shared_wd"]
+        out = out + _shared_expert(p, x)
     return out, aux

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 
 from repro.dist.annotate import BATCH, ann, ann_first_fit
 
+from .layers import rmsnorm
+
 
 def _split_proj(p, x, cfg):
     """in_proj -> (z, xh, Bm, Cm, dt) with conv over (xh|B|C)."""
@@ -139,6 +141,8 @@ def mamba_block(p, x, cfg, conv_state=None, ssm_state=None, decode=False,
     """Full mamba2 block. x: (B, T, D).
 
     Training/prefill: decode=False, returns (out, (conv_state, ssm_state)).
+    With ``cfg.ssm_gated_norm`` the gated output goes through Mamba-2's
+    RMSNorm (weight ``p["norm"]``) before ``out_proj``.
     Decode: T == 1 with states provided; O(1) update.
     Chunked prefill: decode=False with states = the previous chunk's carry
     and ``valid_len`` = real tokens in this (possibly tail-padded) chunk —
@@ -178,7 +182,14 @@ def mamba_block(p, x, cfg, conv_state=None, ssm_state=None, decode=False,
         y = y[:, None].astype(x.dtype)                     # (B, 1, H, P)
 
     y = y.reshape(Bsz, T, di)
-    y = y * jax.nn.silu(z)                                 # gated
+    if cfg.ssm_gated_norm:
+        # Mamba-2's gated RMSNorm over all d_inner channels (n_groups 1),
+        # in f32: w * rmsnorm(y * silu(z))
+        f32 = jnp.float32
+        y = rmsnorm(y.astype(f32) * jax.nn.silu(z.astype(f32)), p["norm"],
+                    cfg.norm_eps).astype(x.dtype)
+    else:
+        y = y * jax.nn.silu(z)                             # gated
     out = y @ p["out_proj"]
     return out, (new_conv, final)
 

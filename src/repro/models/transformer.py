@@ -66,17 +66,20 @@ def init_mlp_params(key, cfg: ArchConfig, kind: str):
 
 
 def init_moe_params(key, cfg: ArchConfig):
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts``; the routed experts' weights for
+    the ``n_held`` this chip holds; the shared expert at its own width."""
+    D, F, E, Eh = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_held
     dt = cfg.activation_dtype()
     ks = jax.random.split(key, 7)
     p = {"router": _dense(ks[0], (D, E), jnp.float32),
-         "wg": _dense(ks[1], (E, D, F), dt, scale=1.0 / np.sqrt(D)),
-         "wu": _dense(ks[2], (E, D, F), dt, scale=1.0 / np.sqrt(D)),
-         "wd": _dense(ks[3], (E, F, D), dt, scale=1.0 / np.sqrt(F))}
+         "wg": _dense(ks[1], (Eh, D, F), dt, scale=1.0 / np.sqrt(D)),
+         "wu": _dense(ks[2], (Eh, D, F), dt, scale=1.0 / np.sqrt(D)),
+         "wd": _dense(ks[3], (Eh, F, D), dt, scale=1.0 / np.sqrt(F))}
     if cfg.shared_expert:
-        p["shared_wg"] = _dense(ks[4], (D, F), dt)
-        p["shared_wu"] = _dense(ks[5], (D, F), dt)
-        p["shared_wd"] = _dense(ks[6], (F, D), dt)
+        Fs = cfg.d_shared
+        p["shared_wg"] = _dense(ks[4], (D, Fs), dt)
+        p["shared_wu"] = _dense(ks[5], (D, Fs), dt)
+        p["shared_wd"] = _dense(ks[6], (Fs, D), dt)
     return p
 
 
@@ -86,7 +89,7 @@ def init_mamba_params(key, cfg: ArchConfig):
     ch = di + 2 * N
     dt = cfg.activation_dtype()
     ks = jax.random.split(key, 3)
-    return {
+    p = {
         "in_proj": _dense(ks[0], (D, 2 * di + 2 * N + H), dt),
         "conv_w": _dense(ks[1], (W, ch), dt, scale=1.0 / np.sqrt(W)),
         "conv_b": jnp.zeros((ch,), dt),
@@ -95,6 +98,9 @@ def init_mamba_params(key, cfg: ArchConfig):
         "D": jnp.ones((H,), jnp.float32),
         "out_proj": _dense(ks[2], (di, D), dt),
     }
+    if cfg.ssm_gated_norm:
+        p["norm"] = jnp.zeros((di,), dt)     # offset from one, as ln1/ln2
+    return p
 
 
 def init_block_params(key, cfg: ArchConfig, spec: LayerSpec):
@@ -151,6 +157,24 @@ def init_params(cfg: ArchConfig, key):
 # ---------------------------------------------------------------------------
 # forward blocks
 
+def _res(h, cfg: ArchConfig):
+    """A sublayer's output as it joins the residual stream: scaled by
+    ``residual_multiplier`` where the configuration has one."""
+    if cfg.residual_multiplier != 1.0:
+        return h * cfg.residual_multiplier
+    return h
+
+
+def _add_counts(counts: dict, aux: dict) -> dict:
+    """Fold one expert layer's row counts into the step's: rows summed,
+    the busiest held expert's rows by max."""
+    if not counts or "rows" not in aux:
+        return counts
+    return {"expert_rows": counts["expert_rows"] + aux["rows"],
+            "expert_rows_max": jnp.maximum(counts["expert_rows_max"],
+                                           aux["rows_max"])}
+
+
 def apply_block(p, x, cfg: ArchConfig, spec: LayerSpec, enc_kv=None,
                 positions=None, lengths=None):
     """Full-sequence block (train / prefill). Returns (x, cache, aux).
@@ -175,12 +199,12 @@ def apply_block(p, x, cfg: ArchConfig, spec: LayerSpec, enc_kv=None,
         cache = {"conv": conv_s, "ssm": ssm_s}
     if cfg.sandwich_norm:
         h = rmsnorm(h, p["ln1_post"], cfg.norm_eps)
-    x = x + h
+    x = x + _res(h, cfg)
 
     if spec.cross_attn:
         h = rmsnorm(x, p["ln_x"], cfg.norm_eps)
         h = cross_attn_block(p["xattn"], h, enc_kv, cfg)
-        x = x + h
+        x = x + _res(h, cfg)
 
     aux = {"load_balance": jnp.zeros((), jnp.float32),
            "router_z": jnp.zeros((), jnp.float32)}
@@ -188,25 +212,51 @@ def apply_block(p, x, cfg: ArchConfig, spec: LayerSpec, enc_kv=None,
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
         if spec.mlp == "moe":
             h, aux = moe_block(p["moe"], h, cfg)
-            aux = {k: v.astype(jnp.float32) for k, v in aux.items()}
+            aux = {k: aux[k].astype(jnp.float32)
+                   for k in ("load_balance", "router_z")}
         else:
             h = mlp_block(p["mlp"], h, spec.mlp)
         if cfg.sandwich_norm:
             h = rmsnorm(h, p["ln2_post"], cfg.norm_eps)
-        x = x + h
+        x = x + _res(h, cfg)
     return x, cache, aux
+
+
+def _decode_ssm_paged(p, h, states, layer, active, cfg: ArchConfig):
+    """One token through an SSM layer whose lanes' states lie in layer
+    ``layer`` of the stacked slot states (n_super, B, ...), which the
+    layer scan carries: the step reads the layer's states and writes its
+    new ones back where they lie, the decoding lanes' new and the others'
+    as they were (DESIGN.md §16)."""
+    conv0 = jax.lax.dynamic_index_in_dim(states["conv"], layer, 0, False)
+    ssm0 = jax.lax.dynamic_index_in_dim(states["ssm"], layer, 0, False)
+    h, (conv_s, ssm_s) = mamba_block(p, h, cfg, conv_state=conv0,
+                                     ssm_state=ssm0, decode=True)
+    conv_s = jnp.where(active[:, None, None], conv_s, conv0)
+    ssm_s = jnp.where(active[:, None, None, None], ssm_s, ssm0)
+    at = (layer, 0, 0, 0)
+    return h, {
+        "conv": jax.lax.dynamic_update_slice(
+            states["conv"], conv_s[None].astype(states["conv"].dtype), at),
+        "ssm": jax.lax.dynamic_update_slice(
+            states["ssm"], ssm_s[None].astype(states["ssm"].dtype),
+            at + (0,))}
 
 
 def apply_block_decode(p, x, cache, pos, cfg: ArchConfig, spec: LayerSpec,
                        enc_kv=None, block_tables=None, active=None,
                        layer=None):
-    """One-token block step.  ``block_tables`` switches attention layers to
-    the paged pool (cache is then the stacked pools dict, "k"/"v" (L, NB,
-    bs, K*hd), of which this block is layer ``layer``, and ``pos`` is the
-    (B,) per-sequence position vector).  ``active``: (B,) bool — lanes
-    that are NOT decoding this step (empty slots, requests still
-    mid-prefill) keep their recurrent SSM states untouched; their
-    attention writes already land in the sink block."""
+    """One-token block step.  Returns (x, new_cache, aux): ``aux`` is an
+    expert layer's (its router losses and row counts), else empty.
+
+    ``layer`` switches the block to the paged cache's stacked layout:
+    attention reads and writes layer ``layer`` of the stacked pools dict,
+    "k"/"v" (L, NB, bs, K*hd), through ``block_tables``, with ``pos`` the
+    (B,) per-sequence position vector; an SSM block reads and writes
+    layer ``layer`` of the stacked slot states, and ``active``: (B,) bool
+    marks the lanes decoding this step — the others (empty slots,
+    requests still mid-prefill) keep their recurrent states untouched,
+    and their attention writes already land in the sink block."""
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if spec.kind == "attn" and block_tables is not None:
         h, new_cache = attn_block_decode_paged(p["attn"], h, cache, layer,
@@ -215,32 +265,34 @@ def apply_block_decode(p, x, cache, pos, cfg: ArchConfig, spec: LayerSpec,
         h, ck, cv = attn_block_decode(p["attn"], h, cache["k"], cache["v"],
                                       pos, cfg, spec)
         new_cache = {"k": ck, "v": cv}
+    elif layer is not None:
+        h, new_cache = _decode_ssm_paged(p["ssm"], h, cache, layer, active,
+                                         cfg)
     else:
         h, (conv_s, ssm_s) = mamba_block(p["ssm"], h, cfg,
                                          conv_state=cache["conv"],
                                          ssm_state=cache["ssm"], decode=True)
-        if active is not None:
-            conv_s = jnp.where(active[:, None, None], conv_s, cache["conv"])
-            ssm_s = jnp.where(active[:, None, None, None], ssm_s,
-                              cache["ssm"])
         new_cache = {"conv": conv_s, "ssm": ssm_s}
     if cfg.sandwich_norm:
         h = rmsnorm(h, p["ln1_post"], cfg.norm_eps)
-    x = x + h
+    x = x + _res(h, cfg)
     if spec.cross_attn:
         h = rmsnorm(x, p["ln_x"], cfg.norm_eps)
         h = cross_attn_block(p["xattn"], h, enc_kv, cfg)
-        x = x + h
+        x = x + _res(h, cfg)
+    aux = {}
     if spec.mlp != "none":
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
         if spec.mlp == "moe":
-            h, _ = moe_block(p["moe"], h, cfg)
+            # lanes that are not decoding route no rows
+            h, aux = moe_block(p["moe"], h, cfg, valid_len=(
+                None if active is None else active.astype(jnp.int32)))
         else:
             h = mlp_block(p["mlp"], h, spec.mlp)
         if cfg.sandwich_norm:
             h = rmsnorm(h, p["ln2_post"], cfg.norm_eps)
-        x = x + h
-    return x, new_cache
+        x = x + _res(h, cfg)
+    return x, new_cache, aux
 
 
 # ---------------------------------------------------------------------------
@@ -280,6 +332,8 @@ def encoder_cross_kv(params, enc_out, cfg):
 
 def embed_tokens(params, tokens, cfg):
     x = params["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     # residual stream: batch over data axes, SEQUENCE over "model" between
     # blocks (sequence parallelism: the saved/remat activations are 1/|model|
     # the size; attention/MLP gather S and return reduce-scattered partials)
@@ -290,6 +344,8 @@ def final_logits(params, x, cfg):
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = (x @ head).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.final_softcap:
         logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
     return logits
@@ -467,6 +523,8 @@ def chunked_ce_loss(params, x, tokens, cfg: ArchConfig):
     def chunk_nll(xc, tc, wc):
         logits = jnp.einsum("bsd,dv->bsv", xc, head).astype(jnp.float32)
         logits = ann(logits, BATCH, None, "model")
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if cfg.final_softcap:
             logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
         lp = jax.nn.log_softmax(logits, -1)
@@ -606,8 +664,9 @@ def decode_step(params, cache, batch, cfg: ArchConfig):
         for i, spec in enumerate(pattern):
             enc_kv = xs["enc"][f"p{i}"] if (spec.cross_attn and
                                             enc_kvs is not None) else None
-            x, nc = apply_block_decode(bp[f"p{i}"], x, layer_cache[f"p{i}"],
-                                       pos, cfg, spec, enc_kv=enc_kv)
+            x, nc, _ = apply_block_decode(bp[f"p{i}"], x,
+                                          layer_cache[f"p{i}"], pos, cfg,
+                                          spec, enc_kv=enc_kv)
             new_caches[f"p{i}"] = nc
         return x, new_caches
 
@@ -706,14 +765,26 @@ def make_paged_cache(cfg: ArchConfig, num_blocks: int, block_size: int,
                 "conv": jnp.zeros((n, max_batch, cfg.conv_width - 1, ch), dt),
                 "ssm": jnp.zeros((n, max_batch, cfg.ssm_heads, cfg.ssm_p,
                                   cfg.ssm_state), jnp.float32)}
-    return {"layers": layers}
+    cache = {"layers": layers}
+    if any(spec.mlp == "moe" for spec in cfg.pattern):
+        cache["counts"] = _zero_counts()
+    return cache
+
+
+def _zero_counts() -> dict:
+    """A serving step's counts of its expert layers' work, written by the
+    step beside the cache: rows the held experts received (summed over
+    layers) and the busiest held expert's rows in any one layer."""
+    return {"expert_rows": jnp.zeros((), jnp.int32),
+            "expert_rows_max": jnp.zeros((), jnp.int32)}
 
 
 def _split_paged(cfg: ArchConfig, layers):
     """The paged cache's layers as (pools, states): the attention
-    positions' stacked KV pools, which the layer scan carries and updates
-    in place, and the SSM positions' per-slot states, small enough to
-    ride the scan as xs/ys."""
+    positions' stacked KV pools and the SSM positions' stacked per-slot
+    states.  The layer scan carries both and each step updates them in
+    place: a decode step writes each SSM layer's lanes, a prefill chunk
+    its one slot's rows (DESIGN.md §9, §16)."""
     pools = {f"p{i}": layers[f"p{i}"] for i, spec in enumerate(cfg.pattern)
              if spec.kind == "attn"}
     states = {name: c for name, c in layers.items() if name not in pools}
@@ -769,8 +840,10 @@ def decode_step_paged(params, cache, batch, cfg: ArchConfig):
     position per lane (0 for inactive lanes, whose writes land in the
     sink block); active (B,) bool — lanes decoding this step (inactive
     lanes' SSM states are preserved).  Returns (logits (B, V), new_cache).
-    The KV pools ride the layer scan's carry: each layer writes its B new
-    rows in place and the kernel reads the layer where it lies.
+    The KV pools and SSM states ride the layer scan's carry: each layer
+    writes its B new KV rows and its lanes' states in place, and the
+    kernel reads the layer where it lies.  A cache with expert layers
+    carries the step's ``counts`` out beside them.
     """
     tokens, tables, pos = batch["tokens"], batch["block_tables"], batch["pos"]
     active = batch["active"]
@@ -778,26 +851,31 @@ def decode_step_paged(params, cache, batch, cfg: ArchConfig):
     pattern = cfg.pattern
 
     def body(carry, xs):
-        x, pools = carry
-        pools = dict(pools)
-        bp, states, layer = xs["params"], xs["states"], xs["layer"]
-        new_states = {}
+        x, pools, states, counts = carry
+        pools, states = dict(pools), dict(states)
+        bp, layer = xs["params"], xs["layer"]
         for i, spec in enumerate(pattern):
             name = f"p{i}"
             if name in pools:
-                x, pools[name] = apply_block_decode(
+                x, pools[name], aux = apply_block_decode(
                     bp[name], x, pools[name], pos, cfg, spec,
-                    block_tables=tables, layer=layer)
+                    block_tables=tables, active=active, layer=layer)
             else:
-                x, new_states[name] = apply_block_decode(
-                    bp[name], x, states[name], pos, cfg, spec, active=active)
-        return (x, pools), new_states
+                x, states[name], aux = apply_block_decode(
+                    bp[name], x, states[name], pos, cfg, spec,
+                    active=active, layer=layer)
+            counts = _add_counts(counts, aux)
+        return (x, pools, states, counts), None
 
     pools, states = _split_paged(cfg, cache["layers"])
-    (x, pools), states = _stack_step(
-        cfg, body, (x, pools), {"params": params["blocks"], "states": states})
+    counts = _zero_counts() if "counts" in cache else {}
+    (x, pools, states, counts), _ = _stack_step(
+        cfg, body, (x, pools, states, counts), {"params": params["blocks"]})
     logits = final_logits(params, x[:, -1:], cfg)
-    return logits[:, 0], {**cache, "layers": {**pools, **states}}
+    new = {**cache, "layers": {**pools, **states}}
+    if counts:
+        new["counts"] = counts
+    return logits[:, 0], new
 
 
 def _apply_block_prefill_paged(p, x, layer_cache, cfg, spec, *, tables,
@@ -806,7 +884,9 @@ def _apply_block_prefill_paged(p, x, layer_cache, cfg, spec, *, tables,
     block writes the chunk's K/V rows into layer ``layer`` of the stacked
     pools (``layer_cache``) through the (1, P) block table (pad rows ->
     sink) and attends against its own P pages gathered back; an SSM block
-    threads the slot's states.  Returns (x, new_layer_cache)."""
+    reads the slot's states from layer ``layer`` of the stacked slot
+    states and writes its new ones back there, one slot's rows.  Returns
+    (x, new_layer_cache, aux), ``aux`` an expert layer's."""
     C = x.shape[1]
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if spec.kind == "attn":
@@ -814,9 +894,10 @@ def _apply_block_prefill_paged(p, x, layer_cache, cfg, spec, *, tables,
         bs = pools["k"].shape[2]
         P = tables.shape[1]
         q, k, v = attn_project_qkv(p["attn"], h, cfg)
-        cos, sin = rope_freqs(positions, cfg.hd, cfg.rope_theta)
-        q = apply_rope(q, cos[None], sin[None])
-        k = apply_rope(k, cos[None], sin[None])
+        if cfg.position_embedding == "rope":
+            cos, sin = rope_freqs(positions, cfg.hd, cfg.rope_theta)
+            q = apply_rope(q, cos[None], sin[None])
+            k = apply_rope(k, cos[None], sin[None])
         # pad rows write garbage into the sink block's row 0, masked out
         # by kv_len
         real = jnp.arange(C) < length
@@ -829,36 +910,41 @@ def _apply_block_prefill_paged(p, x, layer_cache, cfg, spec, *, tables,
         h = paged_context_attention(q, ctx_k, ctx_v, q_offset=start,
                                     kv_len=start + length,
                                     window=spec.window,
-                                    softcap=cfg.attn_softcap)
+                                    softcap=cfg.attn_softcap,
+                                    scale=cfg.attention_multiplier)
         h = jnp.einsum("bshk,hkd->bsd", h, p["attn"]["wo"])
         new_cache = pools
     else:
         conv_all, ssm_all = layer_cache["conv"], layer_cache["ssm"]
-        conv0 = jax.lax.dynamic_slice_in_dim(conv_all, slot, 1, axis=0)
-        ssm0 = jax.lax.dynamic_slice_in_dim(ssm_all, slot, 1, axis=0)
+        at = (layer, slot, 0, 0)
+        conv0 = jax.lax.dynamic_slice(conv_all, at,
+                                      (1, 1) + conv_all.shape[2:])[0]
+        ssm0 = jax.lax.dynamic_slice(ssm_all, at + (0,),
+                                     (1, 1) + ssm_all.shape[2:])[0]
         fresh = start == 0           # first chunk starts from zero state
         conv0 = jnp.where(fresh, jnp.zeros_like(conv0), conv0)
         ssm0 = jnp.where(fresh, jnp.zeros_like(ssm0), ssm0)
         h, (nconv, nssm) = mamba_block(p["ssm"], h, cfg, conv_state=conv0,
                                        ssm_state=ssm0, valid_len=length)
-        conv_all = jax.lax.dynamic_update_slice_in_dim(
-            conv_all, nconv.astype(conv_all.dtype), slot, axis=0)
-        ssm_all = jax.lax.dynamic_update_slice_in_dim(
-            ssm_all, nssm.astype(ssm_all.dtype), slot, axis=0)
-        new_cache = {"conv": conv_all, "ssm": ssm_all}
+        new_cache = {
+            "conv": jax.lax.dynamic_update_slice(
+                conv_all, nconv[None].astype(conv_all.dtype), at),
+            "ssm": jax.lax.dynamic_update_slice(
+                ssm_all, nssm[None].astype(ssm_all.dtype), at + (0,))}
     if cfg.sandwich_norm:
         h = rmsnorm(h, p["ln1_post"], cfg.norm_eps)
-    x = x + h
+    x = x + _res(h, cfg)
+    aux = {}
     if spec.mlp != "none":
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
         if spec.mlp == "moe":
-            h, _ = moe_block(p["moe"], h, cfg)
+            h, aux = moe_block(p["moe"], h, cfg, valid_len=length)
         else:
             h = mlp_block(p["mlp"], h, spec.mlp)
         if cfg.sandwich_norm:
             h = rmsnorm(h, p["ln2_post"], cfg.norm_eps)
-        x = x + h
-    return x, new_cache
+        x = x + _res(h, cfg)
+    return x, new_cache, aux
 
 
 def prefill_chunk_paged(params, cache, batch, cfg: ArchConfig):
@@ -869,7 +955,8 @@ def prefill_chunk_paged(params, cache, batch, cfg: ArchConfig):
     admitted slot; start (scalar) absolute position of tokens[:, 0];
     length (scalar) real tokens in this chunk; slot (scalar) the decode
     lane (SSM state row).  Returns (last_real_token_logits (1, V),
-    new_cache).
+    new_cache); the KV pools and SSM states ride the layer scan's carry,
+    as in ``decode_step_paged``.
     """
     tokens, tables = batch["tokens"], batch["block_tables"]
     start, length, slot = batch["start"], batch["length"], batch["slot"]
@@ -879,25 +966,26 @@ def prefill_chunk_paged(params, cache, batch, cfg: ArchConfig):
     pattern = cfg.pattern
 
     def body(carry, xs):
-        x, pools = carry
-        pools = dict(pools)
-        bp, states, layer = xs["params"], xs["states"], xs["layer"]
-        new_states = {}
+        x, pools, states, counts = carry
+        pools, states = dict(pools), dict(states)
+        bp, layer = xs["params"], xs["layer"]
         for i, spec in enumerate(pattern):
             name = f"p{i}"
-            x, nc = _apply_block_prefill_paged(
-                bp[name], x, pools[name] if name in pools else states[name],
-                cfg, spec, tables=tables, start=start, length=length,
-                slot=slot, positions=positions, layer=layer)
-            if name in pools:
-                pools[name] = nc
-            else:
-                new_states[name] = nc
-        return (x, pools), new_states
+            held = pools if name in pools else states
+            x, held[name], aux = _apply_block_prefill_paged(
+                bp[name], x, held[name], cfg, spec, tables=tables,
+                start=start, length=length, slot=slot, positions=positions,
+                layer=layer)
+            counts = _add_counts(counts, aux)
+        return (x, pools, states, counts), None
 
     pools, states = _split_paged(cfg, cache["layers"])
-    (x, pools), states = _stack_step(
-        cfg, body, (x, pools), {"params": params["blocks"], "states": states})
+    counts = _zero_counts() if "counts" in cache else {}
+    (x, pools, states, counts), _ = _stack_step(
+        cfg, body, (x, pools, states, counts), {"params": params["blocks"]})
     x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     logits = final_logits(params, x_last, cfg)
-    return logits[:, 0], {**cache, "layers": {**pools, **states}}
+    new = {**cache, "layers": {**pools, **states}}
+    if counts:
+        new["counts"] = counts
+    return logits[:, 0], new

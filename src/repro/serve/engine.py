@@ -253,6 +253,13 @@ class Request:
         return len(self.out) >= self.max_new_tokens
 
 
+def _add_expert_counts(counts: dict, got: dict) -> None:
+    """Fold one program's expert counts into the step's span args."""
+    counts["expert_rows"] += int(got["expert_rows"])
+    counts["expert_rows_max"] = max(counts["expert_rows_max"],
+                                    int(got["expert_rows_max"]))
+
+
 class PagedServeEngine:
     """Paged KV-cache + continuous-batching decode (DESIGN.md §9, §14).
 
@@ -742,9 +749,10 @@ class PagedServeEngine:
             self._count(status.value.lower())
 
     # -- device steps -------------------------------------------------------
-    def _prefill_one_chunk(self, slot: int, stats: ServeStats, rec) -> int:
+    def _prefill_one_chunk(self, slot: int, stats: ServeStats, rec,
+                           counts: dict | None = None) -> int:
         """Run one prefill chunk of ``slot``'s sequence; returns its
-        tokens."""
+        tokens.  ``counts`` (traced) takes the chunk's expert counts."""
         req = self.slots[slot]
         C = self.prefill_chunk
         start = req.prefilled
@@ -769,6 +777,9 @@ class PagedServeEngine:
                                                  batch)
             with rec.span("prefill_wait", cat="serve", track=track):
                 logits.block_until_ready()
+                if counts is not None and "counts" in self.cache:
+                    _add_expert_counts(counts,
+                                       jax.device_get(self.cache["counts"]))
         stats.prefill_s += time.time() - t0
         req.prefilled += n
         self.pos[slot] = req.prefilled
@@ -819,12 +830,19 @@ class PagedServeEngine:
         ``prefill_wait``) per chunk, ``first_token``, ``batch_build``,
         ``decode_step`` (``decode_dispatch``, ``decode_sync``) and
         ``retire``.  Its args count the step's work from host integers,
-        with no device sync: ``lanes`` decoded and ``prefill_tokens``."""
+        with no device sync: ``lanes`` decoded and ``prefill_tokens``.  A
+        model with expert layers adds ``expert_rows`` (rows its held
+        experts received over the step's programs and layers) and
+        ``expert_rows_max`` (the busiest held expert's rows in one layer
+        of one program), which the step's programs write beside the cache
+        and the step reads at the syncs it already has."""
         stats = stats if stats is not None else ServeStats()
         rec = obs.get_recorder()
         with rec.span("engine_step", cat="serve", track="serve") as counts:
             if counts is not None:
                 counts.update(lanes=0, prefill_tokens=0)
+                if "counts" in self.cache:
+                    counts.update(expert_rows=0, expert_rows_max=0)
             return self._step(stats, rec, counts)
 
     def _step(self, stats: ServeStats, rec, counts: dict | None) -> int:
@@ -847,7 +865,7 @@ class PagedServeEngine:
             target = min(req.prefilled + self.prefill_chunk, len(req.seq))
             if not self._ensure_blocks(slot, target):
                 continue
-            n = self._prefill_one_chunk(slot, stats, rec)
+            n = self._prefill_one_chunk(slot, stats, rec, counts)
             if counts is not None:
                 counts["prefill_tokens"] += n
             budget -= 1
@@ -881,7 +899,11 @@ class PagedServeEngine:
                                                   batch)
                 sampled = self._sample(logits)
             with rec.span("decode_sync", cat="serve", track="serve"):
-                nxt = np.asarray(sampled)
+                if counts is not None and "counts" in self.cache:
+                    nxt, got = jax.device_get((sampled, self.cache["counts"]))
+                    _add_expert_counts(counts, got)
+                else:
+                    nxt = np.asarray(sampled)
         stats.decode_s += time.time() - t0
         stats.steps += 1
 

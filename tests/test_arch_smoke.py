@@ -101,6 +101,7 @@ def test_full_config_dims_match_assignment(arch):
         "starcoder2-15b": (40, 6144, 48, 4, 24576, 49152),
         "mamba2-130m": (24, 768, 1, 1, 0, 50280),
         "granite-20b": (52, 6144, 48, 1, 24576, 49152),
+        "granite-4.0-h-small": (40, 4096, 32, 8, 768, 100352),
     }[arch]
     c = get_config(arch)
     assert (c.n_layers, c.d_model, c.n_heads, c.n_kv_heads, c.d_ff,
@@ -113,7 +114,8 @@ def test_param_counts_near_nameplate():
               "qwen1.5-0.5b": 0.46e9, "gemma2-2b": 2.6e9,
               "jamba-1.5-large-398b": 398e9, "whisper-base": 74e6,
               "llama4-scout-17b-a16e": 108e9, "starcoder2-15b": 15e9,
-              "mamba2-130m": 130e6, "granite-20b": 20e9}
+              "mamba2-130m": 130e6, "granite-20b": 20e9,
+              "granite-4.0-h-small": 32.2e9}
     for arch, target in expect.items():
         n = get_config(arch).param_count()
         assert 0.6 * target < n < 1.45 * target, (arch, n, target)
@@ -121,6 +123,6 @@ def test_param_counts_near_nameplate():
 
 def test_moe_active_params_smaller():
     for arch in ("dbrx-132b", "llama4-scout-17b-a16e",
-                 "jamba-1.5-large-398b"):
+                 "jamba-1.5-large-398b", "granite-4.0-h-small"):
         c = get_config(arch)
         assert c.param_count(active_only=True) < 0.55 * c.param_count()

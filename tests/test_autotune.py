@@ -23,8 +23,9 @@ def _isolated_cache(tmp_path, monkeypatch):
 
 
 def test_registry_lists_all_kernels():
-    assert registry.ops() == ["flash_attention", "paged_attention",
-                              "rmsnorm", "sample_tokens", "sgd_momentum"]
+    assert registry.ops() == ["expert_gmm", "flash_attention",
+                              "paged_attention", "rmsnorm", "sample_tokens",
+                              "sgd_momentum"]
     for name in registry.ops():
         spec = registry.get(name)
         assert set(spec.defaults) == set(spec.tunables)

@@ -209,3 +209,84 @@ def test_sampler_program_name(spec):
         spec((LANES, CFG.vocab), jnp.float32), spec((LANES,), jnp.float32),
         **kw).compile()
     assert compiled.as_text().startswith("HloModule jit_sample_tokens,")
+
+
+def test_granite_steps_update_states_in_place(spec, monkeypatch):
+    """granite-4.0-h-small's serving steps as its cell runs them (one
+    stage of 10 layers, 9 held experts, 64 lanes, the whole pool): the
+    Mamba layers' slot states ride the layer scan's carry and are updated
+    in place.  A decode step's temporaries stay under one layer's states
+    (268 MB at 64 lanes), where riding the scan as xs/ys made a second
+    copy of all of them (2.45 GB); a prefill chunk's hold no copy of them
+    either (its own f32 context scores are 0.54 GB).  The cache is
+    aliased from input to output, and the grouped expert matmul compiles
+    as the chip's kernel."""
+    from bench import common
+    from repro.models import get_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = common.load_cell("granite-4.0-h-small-pp4-ep8.rag-chat")
+    cfg = common.arch_config(cell.config, cell.reference)
+    eng, nb = cell.config["engine"], cell.config["kv_pool"]["num_blocks"]
+    lanes, chunk = eng["max_batch"], eng["prefill_chunk"]
+    pages = eng["max_len"] // eng["block_size"]
+    model = get_model(cfg)
+    place = lambda tree: jax.tree.map(lambda s: spec(s.shape, s.dtype), tree)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: model.make_paged_cache(nb, eng["block_size"], lanes)))
+    size = sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves(cache["layers"]))
+    states = lanes * cell.config["kv_pool"]["state_bytes_per_lane"]
+    i32 = lambda *shape: spec(shape, jnp.int32)
+    steps = {
+        "decode_paged": ({"tokens": i32(lanes, 1),
+                          "block_tables": i32(lanes, pages), "pos": i32(lanes),
+                          "active": spec((lanes,), jnp.bool_)}, 300e6),
+        "prefill_chunk_paged": ({"tokens": i32(1, chunk),
+                                 "block_tables": i32(1, pages),
+                                 "start": i32(), "length": i32(),
+                                 "slot": i32()}, 0.3 * states)}
+    for name, (batch, most) in steps.items():
+        compiled = jax.jit(getattr(model, name), donate_argnums=(1,)).lower(
+            params, cache, batch).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= size, (name, mem)
+        assert mem.temp_size_in_bytes < most, (name, mem)
+        assert re.search(r"%expert_gmm(\.\d+)? = .*tpu_custom_call",
+                         compiled.as_text()), name
+
+
+def test_hybrid_states_stay_in_place_under_the_layer_scan(spec,
+                                                          monkeypatch):
+    """Five of granite's periods (50 layers, run under ``lax.scan``) at a
+    narrower width, 64 lanes: both serving steps keep their temporaries
+    under a tenth of the slot states.  States riding the scan as xs/ys
+    were written out as a second stacked copy of all of them each step
+    (3.1 GB of decode temporaries for 3.06 GB of states)."""
+    from dataclasses import replace
+    from repro.models import get_model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = replace(get_config("granite-4.0-h-small"), n_layers=50,
+                  d_model=1024, n_heads=8, n_kv_heads=2, ssm_heads=32,
+                  vocab=32768, experts_held=9)
+    lanes, pages, chunk = 64, 64, 512
+    model = get_model(cfg)
+    place = lambda tree: jax.tree.map(lambda s: spec(s.shape, s.dtype), tree)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: model.make_paged_cache(lanes * pages + 1, BLOCK, lanes)))
+    states = sum(a.size * a.dtype.itemsize
+                 for layer in cache["layers"].values() if "ssm" in layer
+                 for a in jax.tree.leaves(layer))
+    i32 = lambda *shape: spec(shape, jnp.int32)
+    steps = {
+        "decode_paged": {"tokens": i32(lanes, 1),
+                         "block_tables": i32(lanes, pages), "pos": i32(lanes),
+                         "active": spec((lanes,), jnp.bool_)},
+        "prefill_chunk_paged": {"tokens": i32(1, chunk),
+                                "block_tables": i32(1, pages), "start": i32(),
+                                "length": i32(), "slot": i32()}}
+    for name, batch in steps.items():
+        mem = jax.jit(getattr(model, name), donate_argnums=(1,)).lower(
+            params, cache, batch).compile().memory_analysis()
+        assert mem.temp_size_in_bytes < states / 10, (name, mem)

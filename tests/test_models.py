@@ -183,7 +183,9 @@ def test_moe_identity_when_experts_equal():
 
 
 def test_moe_capacity_drops_tokens():
-    from repro.models.moe import moe_block
+    """The capacity path (what a layer spread over several devices runs)
+    drops what overflows its capacity; one chip's layer runs drop-free."""
+    from repro.models.moe import moe_capacity
     cfg = reduced(get_config("dbrx-132b"))
     from dataclasses import replace
     cfg = replace(cfg, capacity_factor=0.1)  # force overflow
@@ -194,8 +196,11 @@ def test_moe_capacity_drops_tokens():
          "wu": jax.random.normal(ks[2], (E, D, cfg.d_ff)) * 0.05,
          "wd": jax.random.normal(ks[3], (E, cfg.d_ff, D)) * 0.05}
     x = jax.random.normal(ks[4], (1, 64, D))
-    y, _ = moe_block(p, x, cfg)
+    y, _ = moe_capacity(p, x, cfg)
     # dropped tokens produce zero output rows — at least some survive
     norms = np.linalg.norm(np.asarray(y), axis=-1)
-    assert (norms > 1e-6).any()
+    assert (norms > 1e-6).any() and (norms <= 1e-6).any()
     assert np.all(np.isfinite(np.asarray(y)))
+    from repro.models.moe import moe_block
+    y1, _ = moe_block(p, x, cfg)                # one chip: drop-free
+    assert (np.linalg.norm(np.asarray(y1), axis=-1) > 1e-6).all()
